@@ -18,7 +18,7 @@ from repro.api.backends import SpecBackend
 from repro.api.feedback import (AlphaEma, GammaController, best_gamma,
                                 respec_from_drift)
 from repro.api.placement import (Placement, PlacementError, RolePlacement,
-                                 lower, lower_or_degenerate)
+                                 lower)
 from repro.api.plan import (CacheLayout, DeploymentSpec, ExecutionPlan,
                             GammaSchedule, PlacementPlan, SubmeshSpec)
 from repro.api.planner import Planner
@@ -30,4 +30,4 @@ __all__ = ["AlphaEma", "CacheLayout", "DeploymentSpec", "ExecutionPlan",
            "GammaController", "GammaSchedule", "Placement", "PlacementError",
            "PlacementPlan", "Planner", "RolePlacement", "ServeRequest",
            "Session", "SpecBackend", "SubmeshSpec", "best_gamma", "lower",
-           "lower_or_degenerate", "plan_deployment", "respec_from_drift"]
+           "plan_deployment", "respec_from_drift"]

@@ -7,9 +7,10 @@ makes the decision real. ``lower(plan.placement)`` turns the plan into a
 ``Placement``:
 
   * one ``jax.sharding.Mesh`` per role, carved out of the visible devices
-    (disjoint device sets when they fit — the paper's drafter-PU/target-PU
-    split; overlapping from the front otherwise, the paper's shared-PU
-    fallback where one domain idles during the other's phase);
+    as disjoint device sets — the paper's drafter-PU/target-PU split. A plan
+    whose roles do not fit the visible devices side by side raises
+    ``PlacementError``: it never runs overlapped or single-mesh in its
+    place;
   * a ``ShardingPolicy`` per role (submesh axes named ``data``/``pod``
     become the role's batch axes, everything else its tensor axes), from
     which the ``models/specs.py`` builders derive ``NamedSharding`` trees
@@ -125,6 +126,21 @@ class RolePlacement:
             return cache
         return jax.device_put(cache, self.cache_shardings(model, cache, batch))
 
+    def jit(self, fn, **jit_kw):
+        """``jax.jit(fn)`` whose calls trace under this role's mesh, so the
+        Pallas kernels inside find it and run per device under
+        ``shard_map`` (kernels/ops.py) — XLA cannot partition them itself.
+        Plain ``jax.jit`` when degenerate."""
+        jitted = jax.jit(fn, **jit_kw)
+        if self.mesh is None:
+            return jitted
+        mesh = self.mesh.abstract_mesh
+
+        def on_mesh(*args, **kw):
+            with jax.sharding.use_abstract_mesh(mesh):
+                return jitted(*args, **kw)
+        return on_mesh
+
 
 def _role_policy(spec: SubmeshSpec) -> ShardingPolicy:
     data = tuple(a for a in spec.axes if a in DATA_AXES)
@@ -158,7 +174,6 @@ class Placement:
     drafter: RolePlacement
     target: RolePlacement
     overlap: bool = False              # dispatch next draft under in-flight verify
-    note: str = ""
 
     @property
     def heterogeneous(self) -> bool:
@@ -183,16 +198,14 @@ class Placement:
 
     def describe(self) -> str:
         if not self.heterogeneous:
-            return ("placement: degenerate (single implicit mesh)"
-                    + (f" — {self.note}" if self.note else ""))
+            return "placement: degenerate (single implicit mesh)"
         def one(r: RolePlacement):
             return (f"{r.spec.name}[{len(r.devices)} dev: "
                     f"{','.join(str(d.id) for d in r.devices)}]")
         kind = "disjoint" if self.disjoint else "overlapping"
         return (f"placement: drafter@{one(self.drafter)} "
                 f"target@{one(self.target)} ({kind}"
-                f"{', overlap-dispatch' if self.overlap else ''})"
-                f"{' — ' + self.note if self.note else ''}")
+                f"{', overlap-dispatch' if self.overlap else ''})")
 
 
 DEGENERATE = Placement(drafter=RolePlacement(SubmeshSpec()),
@@ -204,31 +217,23 @@ def lower(plan: PlacementPlan, devices: Optional[Sequence] = None) -> Placement:
 
     Identical drafter/target submeshes (the default replicated plan) lower
     to the DEGENERATE placement — a no-op, token-identical to the
-    mesh-implicit stack. Distinct submeshes get their own meshes: disjoint
-    device sets when ``chips_d + chips_t`` fit the visible devices, else
-    both carved from the front (shared-PU fallback, recorded in ``note``).
-    Raises PlacementError when either submesh alone exceeds the devices.
+    mesh-implicit stack. Distinct submeshes get their own meshes on
+    disjoint device sets; raises PlacementError when ``chips_d + chips_t``
+    exceed the visible devices.
     """
     if plan.drafter == plan.target:
         return DEGENERATE
     devices = list(jax.devices() if devices is None else devices)
     cd, ct = plan.drafter.chips, plan.target.chips
-    note = ""
-    if cd + ct <= len(devices):
-        d_devs, t_devs = devices[:cd], devices[cd:cd + ct]
-    elif max(cd, ct) <= len(devices):
-        d_devs = t_devs = devices
-        note = (f"shared devices: {cd}+{ct} submesh chips > "
-                f"{len(devices)} visible — roles overlap from device 0")
-    else:
+    if cd + ct > len(devices):
         raise PlacementError(
-            f"placement needs {max(cd, ct)} devices for one role, "
-            f"{len(devices)} visible")
+            f"placement needs {cd}+{ct} devices for disjoint drafter/target "
+            f"submeshes, {len(devices)} visible")
     mk = lambda spec, devs: RolePlacement(spec, _role_mesh(spec, devs),
                                           _role_policy(spec))
-    return Placement(drafter=mk(plan.drafter, d_devs),
-                     target=mk(plan.target, t_devs),
-                     overlap=getattr(plan, "overlap", False), note=note)
+    return Placement(drafter=mk(plan.drafter, devices[:cd]),
+                     target=mk(plan.target, devices[cd:cd + ct]),
+                     overlap=getattr(plan, "overlap", False))
 
 
 def role(spec: SubmeshSpec, devices: Optional[Sequence] = None) -> RolePlacement:
@@ -238,15 +243,3 @@ def role(spec: SubmeshSpec, devices: Optional[Sequence] = None) -> RolePlacement
     devices = list(jax.devices() if devices is None else devices)
     return RolePlacement(spec, _role_mesh(spec, devices), _role_policy(spec))
 
-
-def lower_or_degenerate(plan: PlacementPlan,
-                        devices: Optional[Sequence] = None) -> Placement:
-    """``lower`` with a graceful fallback: plans whose submeshes do not fit
-    the visible devices (e.g. a 256-chip plan opened on a laptop) execute
-    degenerately, with the reason recorded on the placement."""
-    try:
-        return lower(plan, devices)
-    except PlacementError as e:
-        return Placement(drafter=RolePlacement(plan.drafter),
-                         target=RolePlacement(plan.target),
-                         note=f"degenerate fallback: {e}")

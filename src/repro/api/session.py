@@ -60,9 +60,8 @@ class Session:
                  plan: ExecutionPlan, *, max_batch: Optional[int] = None,
                  placement=None, tracer=None):
         """``placement``: a pre-lowered ``api.placement.Placement``; None
-        lowers the plan's PlacementPlan against the visible devices (plans
-        whose submeshes do not fit fall back to the degenerate single-mesh
-        lowering, with the reason on ``session.placement.note``).
+        lowers the plan's PlacementPlan against the visible devices (a plan
+        whose submeshes do not fit them raises ``PlacementError``).
 
         ``tracer``: a ``repro.obs.Tracer`` the Session owns for its
         lifetime and threads through the backend (None = disabled tracing,
@@ -77,7 +76,7 @@ class Session:
         self.plan = plan
         self.tracer = tracer if tracer is not None else NULL_TRACER
         if placement is None:
-            placement = placement_mod.lower_or_degenerate(plan.placement)
+            placement = placement_mod.lower(plan.placement)
         self.placement = placement
         self.backend_name = _select_backend(plan, target, drafter)
         if max_batch is None:

@@ -5,14 +5,19 @@ allocation, so every row of a served batch must share a sequence budget.
 This module replaces that with vLLM-style paging:
 
   pool   = {
-    "k": [L, num_blocks, block_size, Kv, D],   # one block pool per layer stack
-    "v": [L, num_blocks, block_size, Kv, D],
+    "k": [L, num_blocks, Kv, block_size, D],   # one block pool per layer stack
+    "v": [L, num_blocks, Kv, block_size, D],
     "block_table": [B, max_blocks_per_row] int32,  # row -> pool block ids
     "index": [B] int32                             # committed tokens per row
   }
 
 Token at absolute position ``p`` of row ``b`` lives in
-``pool[block_table[b, p // block_size], p % block_size]``. Rows own disjoint
+``pool[block_table[b, p // block_size], :, p % block_size]``. The pool is
+head-major inside a block: one kv head of one block is a contiguous
+``[block_size, D]`` tile, which is the unit the Pallas kernels DMA (a TPU
+block's last two dims must be whole array dims or (8, 128)-aligned, so a
+single head cannot be sliced out of a token-major ``[block_size, Kv, D]``
+block). Rows own disjoint
 block sets handed out by the host-side ``BlockAllocator``; memory scales with
 the tokens actually resident, not ``batch * max(len)``.
 
@@ -71,7 +76,7 @@ def init_pool(num_layers, num_blocks, block_size, num_kv_heads, head_dim,
               dtype=jnp.bfloat16):
     """Per-layer-stack block pools (no table — tables are per cache, pools may
     be grouped, e.g. MoE sub-stacks sharing one table)."""
-    shape = (num_layers, num_blocks, block_size, num_kv_heads, head_dim)
+    shape = (num_layers, num_blocks, num_kv_heads, block_size, head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -93,7 +98,7 @@ def write(layer_cache, k_new, v_new, block_table, index):
     """Per-layer paged WRITE (pool update only — the write half of the
     write/read split; ``models.attention.attn_paged`` is the read half).
 
-    layer_cache: {"k": [NB, BS, Kv, D], "v": ...} — this layer's pool slice.
+    layer_cache: {"k": [NB, Kv, BS, D], "v": ...} — this layer's pool slice.
     k_new/v_new: [B, Q, Kv, D] written at positions index..index+Q-1 per row.
 
     Returns the new layer cache. Deliberately does NOT return a gathered
@@ -104,7 +109,7 @@ def write(layer_cache, k_new, v_new, block_table, index):
     Unlike the ring buffer, appends never evict: the write happens first and
     attention reads the post-write pool even for Q > 1.
     """
-    BS = layer_cache["k"].shape[1]
+    BS = layer_cache["k"].shape[2]
     B, Q = k_new.shape[0], k_new.shape[1]
     MB = block_table.shape[1]
     idx = jnp.asarray(index)
@@ -117,8 +122,9 @@ def write(layer_cache, k_new, v_new, block_table, index):
     # the row's last table entry (NULL for released rows) instead of OOB
     blk = block_table[rows, jnp.minimum(pos // BS, MB - 1)]  # [B, Q]
     off = pos % BS
-    k_buf = layer_cache["k"].at[blk, off].set(_to_buf_dtype(k_new, layer_cache["k"].dtype))
-    v_buf = layer_cache["v"].at[blk, off].set(_to_buf_dtype(v_new, layer_cache["v"].dtype))
+    # advanced indices split by the head slice index as [B, Q, Kv, D]
+    k_buf = layer_cache["k"].at[blk, :, off].set(_to_buf_dtype(k_new, layer_cache["k"].dtype))
+    v_buf = layer_cache["v"].at[blk, :, off].set(_to_buf_dtype(v_new, layer_cache["v"].dtype))
     return {"k": k_buf, "v": v_buf}
 
 
@@ -144,17 +150,19 @@ def compact_positions(cache, block_table, src_pos, dst_pos):
     layers at once. The gather completes before the scatter, so overlapping
     src/dst are safe; the tree layout guarantees src >= dst per step (winner
     slots always sit at-or-beyond their committed destination)."""
-    BS = cache["k"].shape[2]
+    BS = cache["k"].shape[3]
     MB = block_table.shape[1]
     B = src_pos.shape[0]
     rows = jnp.arange(B, dtype=jnp.int32)[:, None]
     sblk = block_table[rows, jnp.minimum(src_pos // BS, MB - 1)]
     dblk = block_table[rows, jnp.minimum(dst_pos // BS, MB - 1)]
-    k = cache["k"][:, sblk, src_pos % BS]                    # [L, B, P, Kv, D]
-    v = cache["v"][:, sblk, src_pos % BS]
+    # advanced indices split by slices gather as [B, P, L, Kv, D]; the
+    # scatter below uses the same index form, so the shapes line up
+    k = cache["k"][:, sblk, :, src_pos % BS]
+    v = cache["v"][:, sblk, :, src_pos % BS]
     out = dict(cache)
-    out["k"] = cache["k"].at[:, dblk, dst_pos % BS].set(k)
-    out["v"] = cache["v"].at[:, dblk, dst_pos % BS].set(v)
+    out["k"] = cache["k"].at[:, dblk, :, dst_pos % BS].set(k)
+    out["v"] = cache["v"].at[:, dblk, :, dst_pos % BS].set(v)
     return out
 
 

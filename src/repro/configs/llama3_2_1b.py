@@ -8,6 +8,7 @@ def config():
         name="llama3.2-1b", family="dense", num_layers=16, d_model=2048,
         num_heads=32, num_kv_heads=8, head_dim=64, d_ff=8192, vocab_size=128256,
         rope_theta=500000.0, tie_embeddings=True,
+        embed_init_scale=0.02,             # config.json initializer_range
         source="hf:meta-llama/Llama-3.2-1B",
     )
 
@@ -21,4 +22,5 @@ def drafter_config():
 def smoke_config():
     return config().replace(name="llama3.2-1b-smoke", num_layers=2, d_model=256,
                             num_heads=4, num_kv_heads=2, head_dim=64, d_ff=512,
-                            vocab_size=512, dtype="float32", param_dtype="float32")
+                            vocab_size=512, dtype="float32", param_dtype="float32",
+                            embed_init_scale=None)  # goldens: std-1 table

@@ -248,17 +248,37 @@ def speedup_curve(alpha_grid: Iterable[float], gamma: int, c: float) -> np.ndarr
 
 
 # ---------------------------------------------------------------------------
-# v5e hardware constants (the TPU analogue of the paper's profiled silicon)
+# Per-chip peaks (the TPU analogue of the paper's profiled silicon), keyed by
+# the device_kind JAX reports
 # ---------------------------------------------------------------------------
 @dataclass(frozen=True)
 class HardwareSpec:
-    name: str = "tpu-v5e"
-    peak_flops: float = 197e12        # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9             # bytes/s per chip
-    ici_bw: float = 50e9              # bytes/s per link
+    name: str
+    peak_flops: float                 # bf16 FLOP/s per chip
+    peak_int8_ops: float              # int8 OP/s per chip
+    hbm_bytes: float                  # HBM capacity per chip
+    hbm_bw: float                     # bytes/s per chip
+    ici_bw: float                     # bytes/s per link (4 links per chip)
+    source: str
 
 
-V5E = HardwareSpec()
+PEAKS = {
+    "TPU v5 lite": HardwareSpec(
+        name="TPU v5e", peak_flops=197e12, peak_int8_ops=393e12,
+        hbm_bytes=16e9, hbm_bw=819e9, ici_bw=1600e9 / 8 / 4,
+        source="Google Cloud documentation, 'TPU v5e' (per-chip specs)"),
+}
+
+V5E = PEAKS["TPU v5 lite"]
+
+
+def peaks_for(device_kind: str) -> HardwareSpec:
+    """The peaks row of a device kind (``jax.devices()[0].device_kind``).
+    A kind missing from the table is an error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 
 @dataclass(frozen=True)

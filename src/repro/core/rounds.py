@@ -885,9 +885,10 @@ class PlacedRound:
         # (tokens/length/counters) are NOT, so callers may still read e.g.
         # a prior state's committed length after dispatching the next round
         # (the overlap lookahead loop does exactly that)
-        self._draft_jit = jax.jit(draft, donate_argnums=(3,))
-        self._vc_jit = jax.jit(verify_commit, donate_argnums=(2,))
-        self._drb_jit = jax.jit(drafter_rollback, donate_argnums=(0,))
+        self._draft_jit = placement.drafter.jit(draft, donate_argnums=(3,))
+        self._vc_jit = placement.target.jit(verify_commit, donate_argnums=(2,))
+        self._drb_jit = placement.drafter.jit(drafter_rollback,
+                                              donate_argnums=(0,))
 
     def __call__(self, params_t, params_d, state: RoundState,
                  **tags) -> RoundState:

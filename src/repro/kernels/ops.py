@@ -2,7 +2,11 @@
 
 Handles padding to block multiples, backend selection (interpret=True when no
 TPU is attached — the kernels then execute their bodies on CPU for
-correctness), and dtype plumbing. Model code calls these, never pallas_call
+correctness), dtype plumbing, and partitioning: XLA cannot partition a
+Mosaic kernel, so when the caller traces under a multi-device mesh (a placed
+role's submesh, ``api.placement.RolePlacement.jit``) the serving kernels run
+under ``shard_map`` — attention split over kv heads when they divide the
+mesh, verify on every device. Model code calls these, never pallas_call
 directly.
 """
 from __future__ import annotations
@@ -11,6 +15,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import int8_matmul as _imm
@@ -22,6 +27,25 @@ from repro.kernels import tree_attention as _ta
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _per_device(fn, in_specs, out_specs):
+    """``fn`` as is on one device; under the multi-device mesh the caller
+    is tracing in, ``fn`` under ``shard_map`` with the given specs."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def _head_axes(num_kv_heads):
+    """Mesh axes the kv heads are split over (None = every device computes
+    all heads, when they do not divide the mesh)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or num_kv_heads % mesh.size:
+        return None
+    return tuple(mesh.axis_names)
 
 
 def _pad_to(x, axis, mult):
@@ -56,9 +80,13 @@ def quantized_matmul(x, w_q, sw, *, bm=128, bn=128, bk=128, out_dtype=None):
 
 
 def verify_greedy(draft_tokens, p_logits, *, br=8, bv=2048):
-    """Fused greedy verification (see repro.core.acceptance for the oracle)."""
-    return _sv.verify_greedy_fused(draft_tokens, p_logits, br=br, bv=bv,
-                                   interpret=_interpret())
+    """Fused greedy verification (see repro.core.acceptance for the oracle).
+    On a multi-device mesh every device verifies the whole (gathered)
+    logits, so the result is replicated."""
+    def fn(drafts, logits):
+        return _sv.verify_greedy_fused(drafts, logits, br=br, bv=bv,
+                                       interpret=_interpret())
+    return _per_device(fn, (P(), P()), P())(draft_tokens, p_logits)
 
 
 def flash_attention(q, k, v, *, bq=256, bs=512, window=None, causal=True):
@@ -85,9 +113,23 @@ def paged_attention(q, k_pool, v_pool, block_table, index, *, window=None,
         from repro.models.attention import attn_paged
         return attn_paged(q, k_pool, v_pool, block_table, index,
                           window=window, max_live=max_live)
-    return _pa.paged_flash_attention(q, k_pool, v_pool, block_table, index,
-                                     window=window, interpret=_interpret(),
-                                     max_live=max_live)
+
+    def fn(q, k, v, tbl, idx, *ml):
+        return _pa.paged_flash_attention(q, k, v, tbl, idx, window=window,
+                                         interpret=_interpret(),
+                                         max_live=ml[0] if ml else None)
+    ml = () if max_live is None else (jnp.asarray(max_live, jnp.int32),)
+    return _per_device(fn, *_attn_specs(k_pool.shape[1], 2 + len(ml)))(
+        q, k_pool, v_pool, block_table, index, *ml)
+
+
+def _attn_specs(num_kv_heads, n_replicated):
+    """(in_specs, out_specs) of a paged kernel call: q/out [B, Q, H, D] and
+    pools [NB, Kv, BS, D] split on heads, then ``n_replicated`` small
+    operands (tables, indices, tree masks, bounds)."""
+    ax = _head_axes(num_kv_heads)
+    heads, pool = P(None, None, ax, None), P(None, ax, None, None)
+    return (heads, pool, pool) + (P(),) * n_replicated, heads
 
 
 def tree_attention(q, k_pool, v_pool, block_table, index, depths, bits, *,
@@ -99,10 +141,15 @@ def tree_attention(q, k_pool, v_pool, block_table, index, depths, bits, *,
         from repro.models.attention import attn_tree
         return attn_tree(q, k_pool, v_pool, block_table, index, depths, bits,
                          window=window, max_live=max_live)
-    return _ta.tree_flash_attention(q, k_pool, v_pool, block_table, index,
-                                    depths, bits, window=window,
-                                    interpret=_interpret(),
-                                    max_live=max_live)
+
+    def fn(q, k, v, tbl, idx, dep, bts, *ml):
+        return _ta.tree_flash_attention(q, k, v, tbl, idx, dep, bts,
+                                        window=window, interpret=_interpret(),
+                                        max_live=ml[0] if ml else None)
+    ml = () if max_live is None else (jnp.asarray(max_live, jnp.int32),)
+    return _per_device(fn, *_attn_specs(k_pool.shape[1], 4 + len(ml)))(
+        q, k_pool, v_pool, block_table, index, jnp.asarray(depths, jnp.int32),
+        jnp.asarray(bits, jnp.int32), *ml)
 
 
 def ssd_scan(x, dA, Bm, Cm, *, chunk=128):

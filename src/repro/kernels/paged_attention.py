@@ -38,7 +38,7 @@ NEG_INF = -1e30
 def _kernel(tbl_ref, live_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,
             m_ref, l_ref, acc_ref, *, bs: int, gq: int, window,
             scale: float):
-    """Blocks: q/o [1, 1, R, D]; k/v [1, bs, 1, D] (R = padded Q*gq rows)."""
+    """Blocks: q/o [1, 1, R, D]; k/v [1, 1, bs, D] (R = padded Q*gq rows)."""
     b = pl.program_id(0)
     j = pl.program_id(2)
     n_j = pl.num_programs(2)
@@ -53,8 +53,8 @@ def _kernel(tbl_ref, live_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j < live_ref[b])
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                    # [R, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)                 # [bs, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                    # [bs, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
 
@@ -86,13 +86,13 @@ def _kernel(tbl_ref, live_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_flash_attention(q, k_pool, v_pool, block_table, index, *,
                           window=None, interpret=False, max_live=None):
-    """q: [B, Q, H, D]; k_pool/v_pool: [NB, BS, Kv, D]; block_table: [B, MB];
+    """q: [B, Q, H, D]; k_pool/v_pool: [NB, Kv, BS, D]; block_table: [B, MB];
     index: [B] committed tokens per row (queries sit at index..index+Q-1,
     already written into the pool). H = Kv * gq (GQA-aware). ``max_live``
     caps every row's scanned blocks at ceil(max_live/BS), matching the
     oracle's explicit-bound truncation semantics."""
     B, Q, H, D = q.shape
-    BS, Kv = k_pool.shape[1], k_pool.shape[2]
+    Kv, BS = k_pool.shape[1], k_pool.shape[2]
     MB = block_table.shape[1]
     gq = H // Kv
     scale = D ** -0.5
@@ -114,15 +114,15 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, index, *,
 
     def _kv_map(b, h, j, tbl, live_b, _idx):
         jj = jnp.minimum(j, jnp.maximum(live_b[b] - 1, 0))
-        return (tbl[b, jj], 0, h, 0)
+        return (tbl[b, jj], h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, Kv, MB),
         in_specs=[
             pl.BlockSpec((1, 1, R, D), lambda b, h, j, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, BS, 1, D), _kv_map),
-            pl.BlockSpec((1, BS, 1, D), _kv_map),
+            pl.BlockSpec((1, 1, BS, D), _kv_map),
+            pl.BlockSpec((1, 1, BS, D), _kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, R, D), lambda b, h, j, *_: (b, h, 0, 0)),
         scratch_shapes=[pltpu.VMEM((R, 1), jnp.float32),
@@ -134,6 +134,7 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, index, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, R, D), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_table.astype(jnp.int32), live, idx, qr, k_pool, v_pool)
     return out[:, :, :Q * gq].reshape(B, Kv, Q, gq, D) \
               .transpose(0, 2, 1, 3, 4).reshape(B, Q, H, D)

@@ -53,6 +53,7 @@ def blockwise_argmax(logits, *, br=8, bv=2048, interpret=False):
         scratch_shapes=[pltpu.VMEM((br, 1), jnp.float32),
                         pltpu.VMEM((br, 1), jnp.int32)],
         interpret=interpret,
+        name="verify_argmax",
     )(logits)
 
 

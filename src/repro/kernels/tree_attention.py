@@ -40,7 +40,7 @@ NEG_INF = -1e30
 def _kernel(tbl_ref, live_ref, idx_ref, q_ref, k_ref, v_ref, dep_ref,
             bit_ref, o_ref, m_ref, l_ref, acc_ref, *, bs: int, span: int,
             window, scale: float):
-    """Blocks: q/o [1, 1, R, D]; k/v [1, bs, 1, D]; dep/bit [R, 1]."""
+    """Blocks: q/o [1, 1, R, D]; k/v [1, 1, bs, D]; dep/bit [R, 1]."""
     b = pl.program_id(0)
     j = pl.program_id(2)
     n_j = pl.num_programs(2)
@@ -55,8 +55,8 @@ def _kernel(tbl_ref, live_ref, idx_ref, q_ref, k_ref, v_ref, dep_ref,
     @pl.when(j < live_ref[b])
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                    # [R, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)                 # [bs, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                    # [bs, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
 
@@ -95,13 +95,13 @@ def _kernel(tbl_ref, live_ref, idx_ref, q_ref, k_ref, v_ref, dep_ref,
 def tree_flash_attention(q, k_pool, v_pool, block_table, index, depths,
                          bits, *, window=None, interpret=False,
                          max_live=None):
-    """q: [B, span, H, D]; k_pool/v_pool: [NB, BS, Kv, D]; block_table:
+    """q: [B, span, H, D]; k_pool/v_pool: [NB, Kv, BS, D]; block_table:
     [B, MB]; index: [B] committed tokens per row (the root sits at index,
     nodes at index+1..index+span-1, already written into the pool);
     depths/bits: int32 [span] per-slot depth and ancestor bitmask
     (core/tree.py). H = Kv * gq (GQA-aware)."""
     B, S, H, D = q.shape                                        # S = span
-    BS, Kv = k_pool.shape[1], k_pool.shape[2]
+    Kv, BS = k_pool.shape[1], k_pool.shape[2]
     MB = block_table.shape[1]
     gq = H // Kv
     scale = D ** -0.5
@@ -141,15 +141,15 @@ def tree_flash_attention(q, k_pool, v_pool, block_table, index, depths,
 
     def _kv_map(b, h, j, tbl, live_b, _idx):
         jj = jnp.minimum(j, jnp.maximum(live_b[b] - 1, 0))
-        return (tbl[b, jj], 0, h, 0)
+        return (tbl[b, jj], h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, Kv, MB),
         in_specs=[
             pl.BlockSpec((1, 1, R, D), lambda b, h, j, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, BS, 1, D), _kv_map),
-            pl.BlockSpec((1, BS, 1, D), _kv_map),
+            pl.BlockSpec((1, 1, BS, D), _kv_map),
+            pl.BlockSpec((1, 1, BS, D), _kv_map),
             pl.BlockSpec((R, 1), lambda b, h, j, *_: (0, 0)),
             pl.BlockSpec((R, 1), lambda b, h, j, *_: (0, 0)),
         ],
@@ -163,6 +163,7 @@ def tree_flash_attention(q, k_pool, v_pool, block_table, index, depths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, R, D), q.dtype),
         interpret=interpret,
+        name="tree_attention",
     )(block_table.astype(jnp.int32), live, idx, qr, k_pool, v_pool,
       dep_rows, bit_rows)
     return out[:, :, :S * gq].reshape(B, Kv, S, gq, D) \

@@ -7,7 +7,28 @@ copies had drifted. One parser-builder and one model-pair loader live here.
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 from typing import Tuple
+
+# the persistent compile cache's default home: a fixed path inside the
+# checkout, so a later run finds what an earlier one compiled (a path built
+# from a temp name, pid or time would start empty every run)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is (JAX reads it
+    itself); otherwise the cache lives in ``COMPILE_CACHE_DIR``. Returns the
+    directory in use. Entry points call this under their ``__main__`` guard,
+    so importing them (as the tests do) leaves the cache off."""
+    import jax
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def add_model_args(ap: argparse.ArgumentParser) -> argparse.ArgumentParser:

@@ -290,5 +290,6 @@ def main():
 
 
 if __name__ == "__main__":
+    cli_args.enable_compile_cache()
     main()
 

@@ -249,7 +249,8 @@ def main():
                 save_result(rec, args.tag)
                 if rec["status"] == "skipped":
                     print(f"== {arch} x {shape}: SKIPPED ({rec['reason']})")
-            except Exception as e:  # record failure, keep sweeping
+            except (RuntimeError, ValueError, TypeError) as e:
+                # a lowering/compile failure: record it, keep sweeping
                 print(f"== {arch} x {shape}: FAILED {e}")
                 traceback.print_exc()
                 failures.append((arch, shape, str(e)))

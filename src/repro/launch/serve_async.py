@@ -146,4 +146,5 @@ def main():
 
 
 if __name__ == "__main__":
+    cli_args.enable_compile_cache()
     main()

@@ -29,9 +29,7 @@ def synthetic_requests(rng, n, vocab, prompt_lens=(4, 18), max_news=(4, 24)):
     return reqs
 
 
-def main():
-    from repro.api import DeploymentSpec, Planner, Session
-
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     cli_args.add_model_args(ap)
     cli_args.add_traffic_args(ap)
@@ -43,11 +41,14 @@ def main():
     ap.add_argument("--block-size", type=int, default=8)
     ap.add_argument("--num-blocks", type=int, default=256)
     ap.add_argument("--max-blocks-per-row", type=int, default=16)
-    args = ap.parse_args()
+    return ap
 
-    mt, md, pt, pd, cfg_t = cli_args.build_pair(args.arch, args.smoke)
-    rng = np.random.default_rng(0)
-    reqs = synthetic_requests(rng, args.requests, cfg_t.vocab_size)
+
+def open_session(args, mt, md, pt, pd, reqs):
+    """Plan the deployment for ``reqs`` and open its paged Session: the
+    planner's decisions, then the CLI's block geometry, gamma pin,
+    placement, prefill and overcommit flags on top."""
+    from repro.api import DeploymentSpec, Planner, Session
 
     spec = DeploymentSpec(
         batch_size=args.batch,
@@ -72,12 +73,21 @@ def main():
     plan = cli_args.apply_overcommit_arg(plan, args.overcommit)
     sess = Session(mt, md, pt, pd, plan, max_batch=args.batch,
                    tracer=cli_args.make_tracer(args))
-    if args.placement:
-        print(sess.placement.describe())
     if sess.backend_name != "paged":
         raise SystemExit(
             f"--arch {args.arch} (family {mt.family!r}) cannot take the paged "
             f"backend (KV-cache families only) — use repro.launch.serve")
+    return sess
+
+
+def main():
+    args = make_parser().parse_args()
+    mt, md, pt, pd, cfg_t = cli_args.build_pair(args.arch, args.smoke)
+    rng = np.random.default_rng(0)
+    reqs = synthetic_requests(rng, args.requests, cfg_t.vocab_size)
+    sess = open_session(args, mt, md, pt, pd, reqs)
+    if args.placement:
+        print(sess.placement.describe())
     fault_plan = cli_args.make_fault_plan(args.faults_seed)
     if fault_plan is not None:
         sess.backend.server.inject_faults(fault_plan)
@@ -104,4 +114,5 @@ def main():
 
 
 if __name__ == "__main__":
+    cli_args.enable_compile_cache()
     main()

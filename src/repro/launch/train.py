@@ -19,7 +19,7 @@ from repro.configs import registry
 from repro.configs.base import ShapeConfig
 from repro.data import pipeline
 from repro.launch.mesh import mesh_axis_sizes
-from repro.launch import steps
+from repro.launch import cli_args, steps
 from repro.models.model import build_model
 from repro.models.specs import ShardingPolicy
 from repro.obs import clock
@@ -91,4 +91,5 @@ def main():
 
 
 if __name__ == "__main__":
+    cli_args.enable_compile_cache()
     main()

@@ -142,13 +142,20 @@ def attention(q, k, v, q_pos, kv_pos, *, window=None, scale=None,
 
 
 # ------------------------------------------------------------- paged read path
+def _take_block(pool, blk, dtype):
+    """Gather pool block ``blk[b]`` for every row: [NB, Kv, BS, D] ->
+    [B, BS, Kv, D] token-major (the layout ``_online_step`` reads)."""
+    from repro.cache.kv_cache import _from_buf
+    return _from_buf(jnp.take(pool, blk, axis=0), dtype).swapaxes(1, 2)
+
+
 def attn_paged(q, k_pool, v_pool, block_table, index, *, window=None,
                scale=None, max_live=None, return_stats=False):
     """Block-table-native attention over a paged KV pool (jnp oracle).
 
     q:            [B, Q, H, D] queries at absolute positions index..index+Q-1
                   (already written into the pool by ``paged_kv.write``).
-    k_pool/v_pool:[NB, BS, Kv, D] this layer's block pool, post-write.
+    k_pool/v_pool:[NB, Kv, BS, D] this layer's block pool, post-write.
     block_table:  [B, MB] int32 row -> pool block ids (NULL block = 0).
     index:        [B] (or scalar) committed tokens per row BEFORE this write.
     max_live:     optional live-token bound (max over rows of index+Q); when
@@ -164,10 +171,8 @@ def attn_paged(q, k_pool, v_pool, block_table, index, *, window=None,
     return_stats=True also returns {"blocks_read", "max_blocks"}: the counter
     is carried through the actual loop, so tests can assert the traffic bound.
     """
-    from repro.cache.kv_cache import _from_buf
-
     B, Q, H, D = q.shape
-    BS, Kv = k_pool.shape[1], k_pool.shape[2]
+    Kv, BS = k_pool.shape[1], k_pool.shape[2]
     MB = block_table.shape[1]
     G = H // Kv
     scale = scale if scale is not None else D ** -0.5
@@ -183,8 +188,8 @@ def attn_paged(q, k_pool, v_pool, block_table, index, *, window=None,
     def body(j, carry):
         softmax_carry, n_read = carry
         blk = jnp.take(block_table, j, axis=1)                    # [B]
-        k_j = _from_buf(jnp.take(k_pool, blk, axis=0), q.dtype)   # [B, BS, Kv, D]
-        v_j = _from_buf(jnp.take(v_pool, blk, axis=0), q.dtype)
+        k_j = _take_block(k_pool, blk, q.dtype)                   # [B, BS, Kv, D]
+        v_j = _take_block(v_pool, blk, q.dtype)
         kv_pos = j * BS + jnp.arange(BS, dtype=jnp.int32)         # [BS]
         softmax_carry = _online_step(softmax_carry, qf, k_j, v_j, q_pos,
                                      kv_pos, window, scale)
@@ -270,10 +275,8 @@ def attn_tree(q, k_pool, v_pool, block_table, index, depths, bits, *,
     Same block-bounded online-softmax loop as ``attn_paged``, with the
     causal mask replaced by ``_tree_mask``: the span slots written at
     index..index+span-1 are only visible along each query's root path."""
-    from repro.cache.kv_cache import _from_buf
-
     B, S, H, D = q.shape                                        # S = span
-    BS, Kv = k_pool.shape[1], k_pool.shape[2]
+    Kv, BS = k_pool.shape[1], k_pool.shape[2]
     MB = block_table.shape[1]
     G = H // Kv
     scale = scale if scale is not None else D ** -0.5
@@ -289,8 +292,8 @@ def attn_tree(q, k_pool, v_pool, block_table, index, depths, bits, *,
 
     def body(j, carry):
         blk = jnp.take(block_table, j, axis=1)                   # [B]
-        k_j = _from_buf(jnp.take(k_pool, blk, axis=0), q.dtype)
-        v_j = _from_buf(jnp.take(v_pool, blk, axis=0), q.dtype)
+        k_j = _take_block(k_pool, blk, q.dtype)
+        v_j = _take_block(v_pool, blk, q.dtype)
         kv_pos = j * BS + jnp.arange(BS, dtype=jnp.int32)
         m = _tree_mask(idx, kv_pos, depths, bits, window)
         return _online_step(carry, qf, k_j, v_j, q_pos, kv_pos, window,

@@ -165,11 +165,15 @@ def cache_specs(cfg, cache_shape, pol: ShardingPolicy, batch: int,
                 shard_seq: bool = True):
     """KV/state caches: batch on data when divisible.
 
-    KV ring buffers [L, B, W, Kv, D] additionally shard the sequence axis W on
-    the model axis when divisible (sequence-parallel cache): attention over the
-    cache becomes a sharded contraction that GSPMD resolves with partial
-    softmax terms + a small all-reduce, while the cache itself — the dominant
-    serving tensor — shrinks by the model-axis size per device.
+    Rank-5 KV leaves additionally shard axis 2 on the model axis when
+    divisible. For ring buffers [L, B, W, Kv, D] that is the sequence axis W
+    (sequence-parallel cache): attention over the cache becomes a sharded
+    contraction that GSPMD resolves with partial softmax terms + a small
+    all-reduce. For paged pools [L, NB, Kv, BS, D] it is the kv-head axis,
+    the split the Pallas paged kernels run under ``shard_map``
+    (kernels/ops.py) alongside the head-sharded q projection. Either way
+    the cache — the dominant serving tensor — shrinks by the model-axis size
+    per device.
     """
     b_ax = pol.batch_axis(batch)
     m_size = pol.axis_size(pol.model)

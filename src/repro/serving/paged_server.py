@@ -270,12 +270,12 @@ class PagedSpecServer:
                 def t_fn(pt, prompt, tc):
                     logits, tc, _ = self.target.apply(pt, prompt[:, :-1], tc)
                     return tc, jnp.isfinite(logits).all()
-                t_jit = jax.jit(t_fn, donate_argnums=(2,))
-                d_jit = jax.jit(
+                pm = self.placement
+                t_jit = pm.target.jit(t_fn, donate_argnums=(2,))
+                d_jit = pm.drafter.jit(
                     lambda pd, prompt, dc:
                         self.drafter.apply(pd, prompt[:, :-1], dc)[1],
                     donate_argnums=(2,))
-                pm = self.placement
 
                 def prefill(pt, pd, prompt, tc, dc):
                     tc, ok = t_jit(pt, pm.to_target(prompt), tc)
@@ -338,11 +338,11 @@ class PagedSpecServer:
                 def t_fn(pt, toks, tc):
                     logits, tc, _ = self.target.apply(pt, toks, tc)
                     return tc, jnp.isfinite(logits).all()
-                t_jit = jax.jit(t_fn, donate_argnums=(2,))
-                d_jit = jax.jit(
+                pm = self.placement
+                t_jit = pm.target.jit(t_fn, donate_argnums=(2,))
+                d_jit = pm.drafter.jit(
                     lambda pd, toks, dc: self.drafter.apply(pd, toks, dc)[1],
                     donate_argnums=(2,))
-                pm = self.placement
 
                 def chunk(pt, pd, toks, tc, dc):
                     tc, ok = t_jit(pt, pm.to_target(toks), tc)
@@ -523,7 +523,9 @@ class PagedSpecServer:
         The round is the shared core's ``ar_round`` (core/rounds.py)."""
         if self._ar_jit is None:
             from repro.core import rounds
-            self._ar_jit = jax.jit(
+            jit = (jax.jit if self.placement is None
+                   else self.placement.target.jit)
+            self._ar_jit = jit(
                 lambda pt, st: rounds.ar_round(self.target, pt, st),
                 donate_argnums=(1,))
         if self.placement is not None:
@@ -726,8 +728,8 @@ class PagedSpecServer:
         def per_block(cache):
             total = 0
             for leaf in jax.tree_util.tree_leaves(cache or {}):
-                if getattr(leaf, "ndim", 0) == 5:  # [L, NB, BS, Kv, D] pools
-                    L, _, BS, Kv, D = leaf.shape
+                if getattr(leaf, "ndim", 0) == 5:  # [L, NB, Kv, BS, D] pools
+                    L, _, Kv, BS, D = leaf.shape
                     total += L * BS * Kv * D * jnp.dtype(leaf.dtype).itemsize
             return total
 
@@ -924,8 +926,10 @@ class PagedSpecServer:
             eng = self._engine(self.gamma)
             try:
                 # the injected drafter failure raises BEFORE dispatch (device
-                # state intact, nothing donated) and recovers through the
-                # same path a real mid-flight drafter exception takes
+                # state intact, nothing donated), so the batch can degrade
+                # to AR. Any other error in the round propagates: it may
+                # have consumed the donated state, and hiding it would let
+                # a broken program pass for a slow one.
                 if self.faults.drafter_fails(step_idx):
                     raise DrafterFault(
                         f"injected drafter failure at step {step_idx}")
@@ -937,12 +941,9 @@ class PagedSpecServer:
                 else:
                     self._state = eng._round_jit(self.params_t, self.params_d,
                                                  self._state)
-            except Exception as e:
+            except DrafterFault as e:
                 # degrade the batch to AR (one-way until it drains) instead
-                # of wedging the server. If the failed dispatch already
-                # consumed the donated round state, the AR round below
-                # raises and propagates — honest failure over silently
-                # serving from a dead buffer.
+                # of wedging the server
                 self.metrics.degrade(self.total_rounds,
                                      f"spec round failed: {e}")
                 self._degraded = True

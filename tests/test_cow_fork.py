@@ -72,8 +72,8 @@ def test_fork_declines_under_pressure():
 
 def _pool(L, NB, BS, Kv, D, seed=0):
     k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
-    return {"k": jax.random.normal(k1, (L, NB, BS, Kv, D), jnp.float32),
-            "v": jax.random.normal(k2, (L, NB, BS, Kv, D), jnp.float32)}
+    return {"k": jax.random.normal(k1, (L, NB, Kv, BS, D), jnp.float32),
+            "v": jax.random.normal(k2, (L, NB, Kv, BS, D), jnp.float32)}
 
 
 def test_copy_blocks_duplicates_pool_blocks():
@@ -127,7 +127,7 @@ def test_branch_writes_are_isolated():
 
     def read(table, pos):
         blk = table[pos // BS]
-        return np.asarray(cache["k"][:, blk, pos % BS])
+        return np.asarray(cache["k"][:, blk, :, pos % BS])
 
     for w in range(2):
         for p in range(n_committed):             # shared prefix intact
@@ -158,8 +158,8 @@ def test_compact_positions_paged_and_ring_agree():
     a = BlockAllocator(32, BS, MB, B)
     for b in range(B):
         assert a.ensure(b, n + 5)
-    paged = {"k": jnp.zeros((L, 32, BS, Kv, D), jnp.float32),
-             "v": jnp.zeros((L, 32, BS, Kv, D), jnp.float32),
+    paged = {"k": jnp.zeros((L, 32, Kv, BS, D), jnp.float32),
+             "v": jnp.zeros((L, 32, Kv, BS, D), jnp.float32),
              "block_table": a.device_table(),
              "index": jnp.full((B,), n, jnp.int32)}
     ring = {"k": jnp.zeros((L, B, W, Kv, D), jnp.float32),
@@ -183,7 +183,7 @@ def test_compact_positions_paged_and_ring_agree():
     outr = RING.compact(ring, src, dst)
     rows = jnp.arange(B)[:, None]
     blk = outp["block_table"][rows, dst // BS]
-    got_p = np.asarray(outp["k"][:, blk, dst % BS])      # [L, B, 3, Kv, D]
+    got_p = np.moveaxis(np.asarray(outp["k"][:, blk, :, dst % BS]), 2, 0)
     got_r = np.asarray(outr["k"][:, rows, dst % W])
     want = np.asarray(dense[:, [n + 1, n + 3, n + 4]])   # [B, 3, Kv, D]
     for layer in range(L):

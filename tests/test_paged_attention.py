@@ -24,8 +24,8 @@ def _pool_cache(key, B, n_tokens, BS, MB, Kv, D, num_blocks=None,
     kk, kv_ = jax.random.split(key)
     k_dense = jax.random.normal(kk, (B, S, Kv, D), jnp.float32)
     v_dense = jax.random.normal(kv_, (B, S, Kv, D), jnp.float32)
-    layer = {"k": jnp.zeros((NB, BS, Kv, D), dtype),
-             "v": jnp.zeros((NB, BS, Kv, D), dtype)}
+    layer = {"k": jnp.zeros((NB, Kv, BS, D), dtype),
+             "v": jnp.zeros((NB, Kv, BS, D), dtype)}
     layer = paged_kv.write(layer, k_dense, v_dense, table,
                            jnp.zeros((B,), jnp.int32))
     return layer, table, k_dense, v_dense
@@ -54,6 +54,44 @@ def test_oracle_matches_dense_blocksizes_gqa(BS, MB, H, Kv):
     got = attn_paged(q, layer["k"], layer["v"], table, index)
     S = max(n_tokens) + Q
     want = _dense_ref(q, k_dense[:, :S], v_dense[:, :S], index)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_pool_is_head_major():
+    """Token p, kv head h of row b sits at pool[table[b, p // BS], h,
+    p % BS]: one head of one block is a contiguous [BS, D] tile, the unit
+    the TPU kernels copy."""
+    B, BS, MB, Kv, D = 2, 4, 4, 3, 8
+    layer, table, k_dense, _ = _pool_cache(jax.random.PRNGKey(7), B, [11, 6],
+                                           BS, MB, Kv, D)
+    assert layer["k"].shape == (B * MB + 1, Kv, BS, D)
+    pool, tbl = np.asarray(layer["k"]), np.asarray(table)
+    for b, n in enumerate([11, 6]):
+        for p in range(n):
+            np.testing.assert_array_equal(pool[tbl[b, p // BS], :, p % BS],
+                                          np.asarray(k_dense[b, p]))
+
+
+def test_oracle_int8_pool_matches_dense_dequantized():
+    """int8 pools (fixed-scale KV) take the oracle path on every backend;
+    it reads the head-major int8 blocks and matches dense attention over
+    the dequantized values."""
+    from repro.cache.kv_cache import _from_buf
+    B, Q, H, Kv, D, BS, MB = 2, 3, 4, 2, 8, 4, 6
+    n_tokens = [9, 13]
+    layer, table, _, _ = _pool_cache(jax.random.PRNGKey(8), B,
+                                     [n + Q for n in n_tokens], BS, MB, Kv,
+                                     D, dtype=jnp.int8)
+    q = jax.random.normal(jax.random.PRNGKey(9), (B, Q, H, D), jnp.float32)
+    index = jnp.asarray(n_tokens, jnp.int32)
+    got = attn_paged(q, layer["k"], layer["v"], table, index)
+    S = max(n_tokens) + Q
+    tbl = np.asarray(table)
+    deq = lambda pool: jnp.stack([
+        _from_buf(pool[tbl[b, np.arange(S) // BS], :, np.arange(S) % BS],
+                  jnp.float32) for b in range(B)])       # [B, S, Kv, D]
+    want = _dense_ref(q, deq(layer["k"]), deq(layer["v"]), index)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 

@@ -2,10 +2,10 @@
 
 Three layers of guarantees:
 
-  * the DEGENERATE lowering (default replicated plans, or plans whose
-    submeshes do not fit the visible devices) is a strict no-op — placed
-    engines are token-identical to the pre-placement goldens
-    (tests/goldens/rounds_parity.json);
+  * the DEGENERATE lowering (default replicated plans) is a strict no-op —
+    placed engines are token-identical to the pre-placement goldens
+    (tests/goldens/rounds_parity.json); plans whose submeshes do not fit
+    the visible devices raise instead of running degenerate;
   * DISTINCT-submesh plans really execute draft on the drafter mesh and
     verify/commit on the target mesh (sharding inspection) and stay
     token-identical to the replicated goldens — run under
@@ -91,20 +91,34 @@ def test_degenerate_engine_matches_golden(pair):
 
 
 def test_unlowerable_plan_falls_back_degenerate():
+    """A plan whose submeshes do not fit the visible devices no longer falls
+    back to a degenerate single-mesh run: lowering and Session both raise."""
     big = PlacementPlan(drafter=SubmeshSpec("mx", ("mx",), (4,)),
                         target=SubmeshSpec("mx*my", ("mx", "my"), (16, 16)))
     with pytest.raises(PL.PlacementError):
         PL.lower(big)
-    pm = PL.lower_or_degenerate(big)
-    assert not pm.heterogeneous and "fallback" in pm.note
-    # Session survives a plan it cannot place (degenerate execution)
     plan = dataclasses.replace(
         Planner(DeploymentSpec(cost_coefficient=0.2,
                                adaptive_gamma=False)).plan(),
         placement=big)
     mt = build_model(registry.smoke_config("llama3.2-1b"))
-    sess = Session(mt, mt, None, None, plan)
-    assert not sess.placement.heterogeneous
+    with pytest.raises(PL.PlacementError):
+        Session(mt, mt, None, None, plan)
+
+
+def test_explicit_placement_that_does_not_fit_raises():
+    """``--placement DxT`` needing more devices than are visible raises: the
+    roles never overlap on device 0 and never run as a single mesh."""
+    from repro.launch import cli_args
+    n = len(jax.devices())
+    plan = cli_args.apply_placement_arg(
+        Planner(DeploymentSpec(cost_coefficient=0.2,
+                               adaptive_gamma=False)).plan(), f"{n}x1")
+    with pytest.raises(PL.PlacementError, match=f"{n}\\+1 devices"):
+        PL.lower(plan.placement)
+    mt = build_model(registry.smoke_config("llama3.2-1b"))
+    with pytest.raises(PL.PlacementError):
+        Session(mt, mt, None, None, plan)
 
 
 def test_unsupported_round_configs_reject_placement(pair):

@@ -188,6 +188,28 @@ def test_drafter_fault_degrades_batch_to_ar(pair):
     _assert_pool_whole(srv)
 
 
+def test_non_injected_round_error_propagates(pair):
+    """Only the injected DrafterFault degrades a batch: any other error in
+    the speculative round (a kernel that fails to lower, a dead donated
+    buffer) leaves ``step`` instead of passing for a slow AR batch."""
+    mt, md, pt, pd, cfg = pair
+    scfg = SchedulerConfig(max_batch=2, block_size=4, num_blocks=64,
+                           max_blocks_per_row=12, gamma_max=4,
+                           prefill_buckets=(8, 16))
+    srv = PagedSpecServer(mt, md, pt, pd, scfg, gamma=2)
+
+    def broken_round(*_):
+        raise RuntimeError("Mosaic lowering failed")
+
+    srv._engine(2)._round_jit = broken_round
+    for r in _requests(cfg, shapes=[(6, 10)], seed=1):
+        srv.submit(r)
+    with pytest.raises(RuntimeError, match="Mosaic lowering failed"):
+        while srv.step() is not None:
+            pass
+    assert not srv.metrics.degradations and srv.metrics.n_rounds == 0
+
+
 def test_watchdog_trips_on_straggling_rounds(pair):
     """Virtual fault delays inflate t_round past the watchdog threshold: the
     batch must degrade to AR with a 'watchdog' reason, and outputs stay

@@ -81,6 +81,41 @@ def test_analytic_cost_sanity():
     assert cd.hbm_bytes >= cache  # cache read is a lower bound
 
 
+def test_peaks_are_keyed_by_device_kind():
+    """Peaks come from one table keyed by ``device_kind``; a kind missing
+    from it is an error, never a silent v5e default."""
+    from repro.core import cost_model as cm
+    v5e = cm.peaks_for("TPU v5 lite")
+    assert v5e is cm.V5E and v5e.peak_flops == 197e12
+    assert v5e.hbm_bw == 819e9 and "TPU v5e" in v5e.source
+    with pytest.raises(KeyError, match="TPU v6 lite"):
+        cm.peaks_for("TPU v6 lite")
+
+
+@pytest.mark.parametrize("env", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env):
+    """The persistent compile cache follows JAX_COMPILATION_CACHE_DIR when
+    set (and is then not set in code); otherwise it is the fixed in-checkout
+    ``.jax_cache``. Config writes are captured so the suite's own cache
+    stays off."""
+    from pathlib import Path
+    from repro.launch import cli_args
+    writes = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: writes.__setitem__(k, v))
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    got = cli_args.enable_compile_cache()
+    if env is None:
+        root = Path(cli_args.__file__).resolve().parents[3]
+        assert got == str(root / ".jax_cache")
+        assert writes == {"jax_compilation_cache_dir": got}
+    else:
+        assert got == env and writes == {}
+
+
 def test_hlo_collective_parser():
     from repro.launch.hlo_analysis import collective_bytes
     hlo = """

@@ -33,8 +33,8 @@ def _pool_cache(key, B, n_tokens, BS, MB, Kv, D, dtype=jnp.float32):
     kk, kv_ = jax.random.split(key)
     k_dense = jax.random.normal(kk, (B, S, Kv, D), jnp.float32)
     v_dense = jax.random.normal(kv_, (B, S, Kv, D), jnp.float32)
-    layer = {"k": jnp.zeros((NB, BS, Kv, D), dtype),
-             "v": jnp.zeros((NB, BS, Kv, D), dtype)}
+    layer = {"k": jnp.zeros((NB, Kv, BS, D), dtype),
+             "v": jnp.zeros((NB, Kv, BS, D), dtype)}
     layer = paged_kv.write(layer, k_dense, v_dense, table,
                            jnp.zeros((B,), jnp.int32))
     return layer, table, k_dense, v_dense
