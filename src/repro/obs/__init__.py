@@ -1,12 +1,14 @@
 """Structured telemetry for the speculative-decoding stack.
 
-Three cooperating pieces, all host-side and dependency-free:
+Three cooperating pieces, all host-side:
 
   * ``trace``  — span-based tracing with Chrome-trace/Perfetto export, so a
     served workload renders as a draft/verify/commit timeline across the
-    drafter-mesh/target-mesh rows.
+    drafter-mesh/target-mesh rows; every span is also a
+    ``jax.profiler.TraceAnnotation``, so a captured profile shows it on the
+    device trace's clock.
   * ``events`` — a typed per-round event log (RoundEvent) that subsumes the
-    round-level counters in ``serving/metrics.py`` and streams to JSONL.
+    round-level counters in ``serving/metrics.py``.
   * ``drift``  — an online predicted-vs-measured monitor that runs the
     paper's cost-model validation loop continuously: each measured round is
     compared against the ``cost_model.round_time`` terms the planner used,

@@ -9,17 +9,12 @@ written). This subsumes the round-level counters in
 ``ServingMetrics.alpha_hat()`` exactly (same per-row EMA, parity-tested in
 tests/test_obs.py) — and adds the per-round structure the drift monitor
 and SLO analysis need.
-
-Events stream to JSONL (``stream_to`` for online appends, ``to_jsonl`` for
-a post-hoc dump), one JSON object per line, so a long run can be analysed
-without holding it in memory.
 """
 from __future__ import annotations
 
-import json
 from collections import deque
-from dataclasses import asdict, dataclass, field
-from typing import IO, Dict, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -63,19 +58,14 @@ class RoundEvent:
             return None
         return float(np.mean([a / self.gamma for a in self.accepted]))
 
-    def to_json(self) -> str:
-        return json.dumps(asdict(self), default=float)
-
 
 class RoundEventLog:
-    """Ring-buffered RoundEvent collector with optional JSONL streaming."""
+    """Ring-buffered RoundEvent collector."""
 
-    def __init__(self, capacity: int = 65536, alpha_ema: float = 0.9,
-                 stream: Optional[IO[str]] = None):
+    def __init__(self, capacity: int = 65536, alpha_ema: float = 0.9):
         self.alpha_ema = alpha_ema
         self._events: deque = deque(maxlen=int(capacity))
         self._alpha: Optional[float] = None
-        self._stream = stream
         self.n_rounds = 0
         self.n_spec_rounds = 0
         self.total_emitted = 0
@@ -94,8 +84,6 @@ class RoundEventLog:
                 self._alpha = (alpha_round if self._alpha is None else
                                self.alpha_ema * self._alpha
                                + (1 - self.alpha_ema) * alpha_round)
-        if self._stream is not None:
-            self._stream.write(ev.to_json() + "\n")
 
     # --------------------------------------------------------------- queries
     def events(self) -> List[RoundEvent]:
@@ -126,14 +114,3 @@ class RoundEventLog:
                     sums[key] = sums.get(key, 0.0) + v
                     counts[key] = counts.get(key, 0) + 1
         return {k: sums[k] / counts[k] for k in sums}
-
-    # -------------------------------------------------------------- streaming
-    def stream_to(self, f: IO[str]):
-        """Append every future event to ``f`` as one JSON line each."""
-        self._stream = f
-
-    def to_jsonl(self, path: str) -> str:
-        with open(path, "w") as f:
-            for ev in self._events:
-                f.write(ev.to_json() + "\n")
-        return path

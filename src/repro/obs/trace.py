@@ -2,21 +2,30 @@
 
 Design constraints, in order:
 
-  1. **Free when off.** ``Tracer(enabled=False).span(...)`` returns a shared
-     module-level null context manager — no allocation, no clock read, no
-     lock. Hot loops can keep unconditional ``with tracer.span(...):`` lines.
-  2. **Honest when on.** A span measures host wall time between ``__enter__``
+  1. **On the profiler's clock.** Every span, enabled tracer or not, is also
+     a ``jax.profiler.TraceAnnotation`` carrying its name and tags. While a
+     ``jax.profiler`` trace is being captured the span lands on the host
+     thread's row of that trace, on the same clock as the device's ops, so
+     an idle gap on the device can be put down to the host work that was
+     running; otherwise the annotation records nothing.
+  2. **Nearly free when off.** ``Tracer(enabled=False).span(...)`` returns a
+     bare annotation (about a microsecond with no profiler session): no
+     clock read, no lock, nothing in the ring, ``duration`` 0.0. Hot loops
+     can keep unconditional ``with tracer.span(...):`` lines.
+  3. **Honest when on.** A span measures host wall time between ``__enter__``
      and ``__exit__``. JAX dispatch is async, so callers that want a span to
      mean "device phase time" must call ``jax.block_until_ready`` *inside*
      the span (see ``core/rounds.TracedRound``); callers that want "host
      dispatch time" simply don't block (see ``PlacedRound``). The tracer
      itself never touches device state.
-  3. **Bounded.** Spans land in a ring buffer (``capacity``); a long-running
+  4. **Bounded.** Spans land in a ring buffer (``capacity``); a long-running
      server keeps the most recent window instead of growing without bound.
 
-Spans carry free-form tags. Two are special on export: ``role`` selects the
-timeline row (host / drafter-mesh / target-mesh), ``phase`` becomes the
-event category (draft / verify / commit / ...).
+Spans carry a few scalar tags (a round id, a step index, counts); the
+profiler records each as a stat of the span's event. Two are special on the
+Chrome export: ``role`` selects the timeline row (host / drafter-mesh /
+target-mesh), ``phase`` becomes the event category (draft / verify /
+commit / ...).
 """
 from __future__ import annotations
 
@@ -25,6 +34,8 @@ import threading
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import clock as _clock
 
@@ -46,29 +57,18 @@ class Span:
         return self.t1 - self.t0
 
 
-class _NullSpan:
-    """Shared no-op context manager returned by a disabled tracer."""
+class _BareSpan(TraceAnnotation):
+    """A disabled tracer's span: the profiler annotation alone."""
     __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-    @property
-    def duration(self) -> float:
-        return 0.0
-
-
-_NULL_SPAN = _NullSpan()
+    duration = 0.0
 
 
 class _LiveSpan:
-    __slots__ = ("_tracer", "name", "tags", "t0", "t1", "depth")
+    __slots__ = ("_tracer", "_ann", "name", "tags", "t0", "t1", "depth")
 
     def __init__(self, tracer: "Tracer", name: str, tags: Dict[str, Any]):
         self._tracer = tracer
+        self._ann = TraceAnnotation(name, **tags)
         self.name = name
         self.tags = tags
         self.t0 = 0.0
@@ -77,6 +77,7 @@ class _LiveSpan:
 
     def __enter__(self) -> "_LiveSpan":
         tr = self._tracer
+        self._ann.__enter__()
         self.depth = tr._enter_depth()
         self.t0 = tr.clock()
         return self
@@ -86,6 +87,7 @@ class _LiveSpan:
         self.t1 = tr.clock()
         tr._exit_depth()
         tr._record(self)
+        self._ann.__exit__(*exc)
         return False
 
     @property
@@ -107,9 +109,10 @@ class Tracer:
     # ------------------------------------------------------------- recording
     def span(self, name: str, **tags):
         """Open a span. Use as ``with tracer.span("draft", phase="draft"):``.
-        Returns a shared null object when disabled (no allocation)."""
+        Disabled, the span is the profiler annotation alone: it reads no
+        clock, records nothing here and its ``duration`` is 0.0."""
         if not self.enabled:
-            return _NULL_SPAN
+            return _BareSpan(name, **tags)
         return _LiveSpan(self, name, tags)
 
     def _enter_depth(self) -> int:

@@ -33,7 +33,10 @@ benchmarks/bench_serving_slo.py.
 Every ``StreamEvent`` carries the round's ``RoundEvent.round`` id, so a
 stream joins the obs layer: TTFT decomposes into queue-wait
 (``RequestRecord.queue_wait``), prefill (the admission round's prefill
-span) and decode (the first round's ``RoundEvent.t_round``).
+span) and decode (the first round's ``RoundEvent.t_round``). The drain and
+the fan-out run under the server tracer's ``frontend.drain`` and
+``frontend.fanout`` spans, so a captured ``jax.profiler`` trace shows them
+beside the server step's own.
 """
 from __future__ import annotations
 
@@ -183,9 +186,10 @@ class AsyncSpecServer:
         """Worker-thread body: move pending submissions into the scheduler
         (arrival-time-stamped), then run one serving round. The only code
         that mutates scheduler/allocator/device state."""
-        while self._pending:
-            req, t_submit = self._pending.popleft()
-            self.server.sched.submit(req, submitted=t_submit)
+        with self.server.tracer.span("frontend.drain"):
+            while self._pending:
+                req, t_submit = self._pending.popleft()
+                self.server.sched.submit(req, submitted=t_submit)
         info = self.server.step()
         if info is not None:
             info["t"] = self.now()
@@ -207,7 +211,9 @@ class AsyncSpecServer:
                 except asyncio.TimeoutError:
                     pass
                 continue
-            await self._fanout(info)
+            with self.server.tracer.span("frontend.fanout",
+                                         round=info["round"]):
+                await self._fanout(info)
 
     async def _fanout(self, info: dict):
         for rid, toks in info["streams"].items():

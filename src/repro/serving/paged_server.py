@@ -403,25 +403,29 @@ class PagedSpecServer:
             return state, None
         padded = np.zeros(C, np.int32)
         padded[:e - s] = prompt[s:e]
-        t0 = self.tracer.clock()
-        # fresh per-chunk uploads of the one-row table — never a slice of
-        # the donated batch-wide device table (see _prefill_into's aliasing
-        # note); two independent uploads for the two donated views
-        t_row = jnp.asarray(self.alloc.table[b:b + 1])
-        d_row = jnp.asarray(self.alloc.table[b:b + 1])
-        if self.placement is not None:
-            t_row = self.placement.to_target(t_row)
-            d_row = self.placement.to_drafter(d_row)
-        tc_view = {**state.tcache, "block_table": t_row,
-                   "index": jnp.full((1,), s, jnp.int32)}
-        dc_view = {**state.dcache, "block_table": d_row,
-                   "index": jnp.full((1,), s, jnp.int32)}
+        # the span and RoundEvent.t_prefill time one interval: uploads, the
+        # chunk program and its ``ok`` sync
         with self.tracer.span("prefill_chunk", phase="prefill", role="target",
                               rid=req.rid, start=s, end=e):
+            t0 = self.tracer.clock()
+            # fresh per-chunk uploads of the one-row table — never a slice
+            # of the donated batch-wide device table (see _prefill_into's
+            # aliasing note); two independent uploads for the two donated
+            # views
+            t_row = jnp.asarray(self.alloc.table[b:b + 1])
+            d_row = jnp.asarray(self.alloc.table[b:b + 1])
+            if self.placement is not None:
+                t_row = self.placement.to_target(t_row)
+                d_row = self.placement.to_drafter(d_row)
+            tc_view = {**state.tcache, "block_table": t_row,
+                       "index": jnp.full((1,), s, jnp.int32)}
+            dc_view = {**state.dcache, "block_table": d_row,
+                       "index": jnp.full((1,), s, jnp.int32)}
             tc, dc, ok = self._chunk_fn()(self.params_t, self.params_d,
                                           jnp.asarray(padded[None]),
                                           tc_view, dc_view)
-        ok = bool(jax.device_get(ok))
+            ok = bool(jax.device_get(ok))
+            self._round_prefill_t += self.tracer.clock() - t0
         # merge: pools carry the new KV; the batch tables/indices are kept
         # (this row's merged index is set once, at completion)
         state = state._replace(
@@ -433,7 +437,6 @@ class PagedSpecServer:
         self._prefill_chunks[b] += 1
         self._round_prefill_tokens += e - s
         self._round_prefill_chunks += 1
-        self._round_prefill_t += self.tracer.clock() - t0
         return state, ok
 
     def _complete_prefill(self, state: RowState, b: int,
@@ -842,27 +845,41 @@ class PagedSpecServer:
         ``run()`` is exactly ``while step() is not None`` — the synchronous
         and async serving paths share this one round loop, which is what
         keeps their token streams byte-identical.
+
+        The step's host work runs under tracer spans (profiler annotations
+        even when the tracer is off): ``server.step`` around it all, then
+        ``step.admit``, ``step.prefill``, ``step.tables``, ``step.round``
+        (``round.dispatch``, ``round.sync``; the same interval as
+        ``RoundEvent.t_round``) and ``step.harvest`` (``harvest.pull``).
         """
+        with self.tracer.span("server.step", step=self.total_steps):
+            return self._step()
+
+    def _step(self) -> Optional[Dict]:
         if self._state is None:
             self._state = self._empty_state()
             self._lengths = np.array(self._state.length)
         step_idx = self.total_steps
         self.total_steps += 1
-        delta = self.faults.pool_delta(step_idx)
-        if delta > 0:
-            self.alloc.seize(delta)
-        elif delta < 0:
-            self.alloc.release_seized(-delta)
-        cancelled = self._process_cancels()
-        self._round_prefill_tokens = 0
-        self._round_prefill_chunks = 0
-        self._round_prefill_t = 0.0
-        self._state = self._refill(self._state, self._lengths)
+        tr = self.tracer
+        with tr.span("step.admit"):
+            delta = self.faults.pool_delta(step_idx)
+            if delta > 0:
+                self.alloc.seize(delta)
+            elif delta < 0:
+                self.alloc.release_seized(-delta)
+            cancelled = self._process_cancels()
+            self._round_prefill_tokens = 0
+            self._round_prefill_chunks = 0
+            self._round_prefill_t = 0.0
+            self._state = self._refill(self._state, self._lengths)
+            expired = self.sched.drain_expired()
         # interleaved chunked prefill: one chunk program per step, BEFORE the
         # decode round, so a row whose suffix completes decodes this step
-        self._state = self._advance_prefills(self._state)
-        self._state = self._sync_tables(self._state)
-        expired = self.sched.drain_expired()
+        with tr.span("step.prefill"):
+            self._state = self._advance_prefills(self._state)
+        with tr.span("step.tables"):
+            self._state = self._sync_tables(self._state)
         if not any(r is not None for r in self._slots):
             self._batch_drained()
             failed = self._drain_failed()
@@ -907,9 +924,10 @@ class PagedSpecServer:
 
         # overcommit: grow every live row to this round's block demand,
         # evicting victims when the pool is dry; tables changed -> re-sync
-        self._state, preempted = self._ensure_capacity(self._state)
-        preempted += self._drain_aborted(preempted)
-        self._state = self._sync_tables(self._state)
+        with tr.span("step.tables"):
+            self._state, preempted = self._ensure_capacity(self._state)
+            preempted += self._drain_aborted(preempted)
+            self._state = self._sync_tables(self._state)
         if not any(r is not None for r in self._slots):
             # extreme pressure evicted the whole batch; deliver and retry
             self._batch_drained()
@@ -920,8 +938,63 @@ class PagedSpecServer:
 
         queue_depth = len(self.sched.queue)
         prev_len = self._lengths
-        phase_t: dict = {}
-        t0 = self.tracer.clock()
+        with tr.span("step.round", round=self.total_rounds, gamma=self.gamma):
+            t0 = tr.clock()
+            with tr.span("round.dispatch"):
+                phase_t = self._dispatch_round(step_idx)
+            # account AFTER execution so a degraded round is charged as the
+            # AR round that actually ran, not the spec round that died
+            blocks_read, blocks_written = self._account_round(prev_len)
+            self.total_rounds += 1
+            # ONE host sync per round: lengths + active in a single pull;
+            # the harvest/refill below reuse the same snapshot
+            with tr.span("round.sync"):
+                lengths, active = map(np.array, jax.device_get(
+                    (self._state.length, self._state.active)))
+            fault_delay = self.faults.round_delay(step_idx)
+            t_round = tr.clock() - t0 + fault_delay  # dispatch -> sync
+                                   # (+ injected virtual straggle, if any)
+        if self.gamma > 0 and self.watchdog.observe(t_round):
+            self.metrics.degrade(self.total_rounds,
+                                 "watchdog: straggling speculative rounds")
+            self._degraded = True  # takes effect next round
+        self._lengths = lengths
+        if self.faults.corrupts(step_idx):
+            self._corrupt_one_row(lengths)
+        with tr.span("step.harvest"):
+            emitted = lengths - prev_len
+            rids = [r.rid if r is not None else None for r in self._slots]
+            self.metrics.record_round(np.maximum(emitted - 1, 0), self.gamma,
+                                      active, rids)
+            streams = self._harvest_streams(prev_len, lengths)
+            ev_lengths = lengths.copy()   # _harvest's refill mutates
+                                          # `lengths` in place for newly
+                                          # admitted rows; the event must see
+                                          # THIS round's commit
+            done_before = len(self.done)
+            self._state = self._harvest(self._state, lengths)
+            expired += self.sched.drain_expired()  # harvest-refill expiries
+            failed = self._drain_failed()
+            self._record_event(prev_len, ev_lengths, active, rids, t_round,
+                               phase_t, blocks_read, blocks_written,
+                               queue_depth, n_preempted=len(preempted),
+                               n_expired=len(expired), n_failed=len(failed),
+                               fault_delay=fault_delay)
+        return {"streams": streams,
+                "finished": [r.rid for r in self.done[done_before:]],
+                "cancelled": cancelled,
+                "expired": expired,
+                "failed": failed,
+                "preempted": preempted,
+                "round": self.total_rounds - 1,
+                "queue_depth": queue_depth,
+                "n_live": int(np.sum(active))}
+
+    def _dispatch_round(self, step_idx: int) -> dict:
+        """Dispatch this step's round onto ``self._state``: the jitted
+        speculative round, or the AR round (gamma 0, or a drafter failure
+        that degrades the batch). Returns the traced round's phase times
+        ({} off the traced path)."""
         if self.gamma > 0:
             eng = self._engine(self.gamma)
             try:
@@ -937,10 +1010,10 @@ class PagedSpecServer:
                     self._state = eng._round_jit(
                         self.params_t, self.params_d, self._state,
                         round=self.total_rounds, gamma=self.gamma)
-                    phase_t = eng._round_jit.last_phase_times
-                else:
-                    self._state = eng._round_jit(self.params_t, self.params_d,
-                                                 self._state)
+                    return eng._round_jit.last_phase_times
+                self._state = eng._round_jit(self.params_t, self.params_d,
+                                             self._state)
+                return {}
             except DrafterFault as e:
                 # degrade the batch to AR (one-way until it drains) instead
                 # of wedging the server
@@ -951,55 +1024,13 @@ class PagedSpecServer:
                 with self.tracer.span("ar_round", phase="verify",
                                       role="target", round=self.total_rounds):
                     self._state = self._ar_round(self._state)
-        else:
-            with self.tracer.span("ar_round", phase="verify",
-                                  role="target", round=self.total_rounds):
-                self._state = self._ar_round(self._state)
-                if self.tracer.enabled:
-                    jax.block_until_ready(self._state.length)
-        # account AFTER execution so a degraded round is charged as the AR
-        # round that actually ran, not the spec round that died
-        blocks_read, blocks_written = self._account_round(prev_len)
-        self.total_rounds += 1
-        # ONE host sync per round: lengths + active in a single pull; the
-        # harvest/refill below reuse the same snapshot
-        lengths, active = map(np.array, jax.device_get(
-            (self._state.length, self._state.active)))
-        fault_delay = self.faults.round_delay(step_idx)
-        t_round = self.tracer.clock() - t0 + fault_delay  # dispatch -> sync
-                                   # (+ injected virtual straggle, if any)
-        if self.gamma > 0 and self.watchdog.observe(t_round):
-            self.metrics.degrade(self.total_rounds,
-                                 "watchdog: straggling speculative rounds")
-            self._degraded = True  # takes effect next round
-        self._lengths = lengths
-        if self.faults.corrupts(step_idx):
-            self._corrupt_one_row(lengths)
-        emitted = lengths - prev_len
-        rids = [r.rid if r is not None else None for r in self._slots]
-        self.metrics.record_round(np.maximum(emitted - 1, 0), self.gamma,
-                                  active, rids)
-        streams = self._harvest_streams(prev_len, lengths)
-        ev_lengths = lengths.copy()   # _harvest's refill mutates `lengths`
-                                      # in place for newly admitted rows; the
-                                      # event must see THIS round's commit
-        done_before = len(self.done)
-        self._state = self._harvest(self._state, lengths)
-        expired += self.sched.drain_expired()   # harvest-refill expiries
-        failed = self._drain_failed()
-        self._record_event(prev_len, ev_lengths, active, rids, t_round,
-                           phase_t, blocks_read, blocks_written, queue_depth,
-                           n_preempted=len(preempted), n_expired=len(expired),
-                           n_failed=len(failed), fault_delay=fault_delay)
-        return {"streams": streams,
-                "finished": [r.rid for r in self.done[done_before:]],
-                "cancelled": cancelled,
-                "expired": expired,
-                "failed": failed,
-                "preempted": preempted,
-                "round": self.total_rounds - 1,
-                "queue_depth": queue_depth,
-                "n_live": int(np.sum(active))}
+                return {}
+        with self.tracer.span("ar_round", phase="verify",
+                              role="target", round=self.total_rounds):
+            self._state = self._ar_round(self._state)
+            if self.tracer.enabled:
+                jax.block_until_ready(self._state.length)
+        return {}
 
     def _corrupt_one_row(self, lengths):
         """Fault injection: poison the newest committed token of the first
@@ -1032,7 +1063,8 @@ class PagedSpecServer:
             if not self.collect_streams or cur <= int(prev_len[b]):
                 continue
             if tok_host is None:   # one bulk pull for all emitting rows
-                tok_host = np.asarray(jax.device_get(self._state.tokens))
+                with self.tracer.span("harvest.pull"):
+                    tok_host = np.asarray(jax.device_get(self._state.tokens))
             new = tok_host[b, int(prev_len[b]):cur].copy()
             if ((new < 0) | (new >= self._vocab)).any():
                 self._fail_row(b, req, cur)

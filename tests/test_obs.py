@@ -1,8 +1,9 @@
 """Observability layer (repro.obs): span tracing, round events, drift.
 
 Four contracts:
-  * Tracer — spans nest, export to valid Chrome-trace JSON, and cost
-    nothing when disabled (shared null span, zero recorded state);
+  * Tracer — spans nest, export to valid Chrome-trace JSON, record
+    nothing when disabled (a profiler annotation only), and land on a
+    captured jax.profiler trace, nested as the server step runs them;
   * RoundEventLog — alpha_hat() reproduces ServingMetrics.alpha_hat()
     exactly (same per-row EMA, unclamped) from typed RoundEvents;
   * DriftMonitor — flags an injected 2x verify slowdown, stays quiet when
@@ -13,9 +14,10 @@ Four contracts:
     SAME tokens as the untraced fused round, and calibrates a drift
     monitor whose evidence re-enters the Planner (respec_from_drift).
 """
-import io
+import glob
 import json
 import math
+import os
 
 import jax
 import numpy as np
@@ -77,13 +79,17 @@ def test_chrome_trace_export(tmp_path):
 
 
 def test_disabled_tracer_is_noop():
-    tr = Tracer(enabled=False)
+    clk = ManualClock()
+    reads = []
+    tr = Tracer(enabled=False, clock=lambda: reads.append(1) or clk())
     s1 = tr.span("a", phase="draft")
-    s2 = tr.span("b", role="host")
-    assert s1 is s2                       # shared null object, no allocation
+    s2 = tr.span("b", role="host", round=3)
     with s1:
-        pass
-    assert s1.duration == 0.0
+        with s2:
+            clk.advance(1.0)
+    # only a profiler annotation: no clock read, nothing in the ring
+    assert s1.duration == 0.0 and s2.duration == 0.0
+    assert reads == []
     assert tr.spans() == [] and tr.count() == 0
     assert tr.phase_totals() == {}
     # the module singleton every default flows through
@@ -136,19 +142,13 @@ def test_round_event_alpha_and_hist_parity():
     assert log.n_spec_rounds == m.n_spec_rounds
 
 
-def test_round_event_jsonl_stream(tmp_path):
-    buf = io.StringIO()
-    log = RoundEventLog(stream=buf)
+def test_round_event_alpha_round_and_phase_means():
+    log = RoundEventLog()
     for k in range(3):
         log.record(RoundEvent(round=k, gamma=4, n_active=2, accepted=(2, 4),
                               emitted=8, t_round=0.01, t_draft=0.004,
                               blocks_read=12, rids=(1, 2), t_wall=1000.0 + k))
-    lines = [json.loads(l) for l in buf.getvalue().splitlines()]
-    assert len(lines) == 3
-    assert lines[0]["accepted"] == [2, 4] and lines[2]["round"] == 2
-    path = tmp_path / "events.jsonl"
-    log.to_jsonl(str(path))
-    assert len(path.read_text().splitlines()) == 3
+    assert [ev.round for ev in log.events()] == [0, 1, 2]
     assert log.events()[0].alpha_round == pytest.approx(0.75)
     assert log.phase_means()["t_draft"] == pytest.approx(0.004)
 
@@ -343,3 +343,121 @@ def test_traced_paged_serving_end_to_end(tmp_path):
     ev = traced.drift.evidence()
     assert ev is not None and 0 < ev["c"] < 2.0
     assert traced.events.n_rounds == traced.metrics.n_rounds
+
+
+# ----------------------------------------------- spans on the profiler's clock
+
+STEP_SPANS = ("server.step", "step.admit", "step.prefill", "step.tables",
+              "step.round", "round.dispatch", "round.sync", "step.harvest",
+              "harvest.pull")
+
+
+def _profile_spans(log_dir):
+    """(name, start_ns, end_ns, stats) of the server step's spans on the
+    host plane of the newest profile under ``log_dir``."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                            recursive=True), key=os.path.getmtime)[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            out += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                     dict(ev.stats))
+                    for ev in line.events if ev.name in STEP_SPANS]
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _inside(child, parent):
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def test_server_step_spans_land_in_the_profile(tmp_path):
+    """With the tracer OFF, a profiled run still shows every step's host
+    work as nested spans on the host plane, with the round tags equal to
+    the RoundEvent ids, and serves the unprofiled tokens."""
+    mt, md, pt, pd, cfg = _pair("llama3.2-1b")
+    scfg = SchedulerConfig(max_batch=2, block_size=4, num_blocks=32,
+                           max_blocks_per_row=8, gamma_max=4,
+                           prefill_chunk=4)
+    wave = _wave(cfg, 5)[:3]
+
+    def serve():
+        srv = PagedSpecServer(mt, md, pt, pd, scfg, gamma=2)
+        srv.collect_streams = True          # the streaming pull runs too
+        for r in wave:
+            srv.submit(ServeRequest(r.rid, r.prompt, r.max_new))
+        return srv, {r.rid: np.asarray(r.tokens) for r in srv.run()}
+
+    _, ref = serve()                        # pays compilation
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        srv, got = serve()
+    finally:
+        jax.profiler.stop_trace()
+    assert got.keys() == ref.keys()
+    for rid in ref:
+        np.testing.assert_array_equal(got[rid], ref[rid])
+
+    spans = _profile_spans(str(tmp_path))
+    steps = [s for s in spans if s[0] == "server.step"]
+    assert [s[3]["step"] for s in steps] == list(range(srv.total_steps))
+    rounds = [s for s in spans if s[0] == "step.round"]
+    assert [s[3]["round"] for s in rounds] == \
+        [ev.round for ev in srv.events.events()]
+    assert all(s[3]["gamma"] == 2 for s in rounds)
+    for r in rounds:
+        step = next(s for s in steps if _inside(r, s))
+        kids = {c[0] for c in spans if c is not step and _inside(c, step)}
+        assert {"step.admit", "step.prefill", "step.tables", "step.round",
+                "round.dispatch", "round.sync", "step.harvest"} <= kids
+        assert sum(c[0] in ("round.dispatch", "round.sync")
+                   and _inside(c, r) for c in spans) == 2
+    harvests = [s for s in spans if s[0] == "step.harvest"]
+    pulls = [s for s in spans if s[0] == "harvest.pull"]
+    assert pulls and all(any(_inside(p, h) for h in harvests)
+                         for p in pulls)
+    # nothing else opens inside the dispatch or the sync
+    for leaf in (s for s in spans if s[0] in ("round.dispatch",
+                                               "round.sync")):
+        assert not any(c is not leaf and _inside(c, leaf) for c in spans)
+
+
+def test_prefill_chunk_span_times_t_prefill():
+    """An enabled tracer's ``prefill_chunk`` spans and the round events'
+    ``t_prefill`` time one interval: per step that ran a round, the
+    chunk spans inside its ``server.step`` sum to its event's t_prefill."""
+    mt, md, pt, pd, cfg = _pair("llama3.2-1b")
+    scfg = SchedulerConfig(max_batch=3, block_size=4, num_blocks=64,
+                           max_blocks_per_row=12, gamma_max=6,
+                           prefill_chunk=4)
+    tracer = Tracer()
+    srv = PagedSpecServer(mt, md, pt, pd, scfg, tracer=tracer)
+    for r in _wave(cfg, 11):
+        srv.submit(r)
+    srv.run()
+    spans = tracer.spans()
+
+    def inside(c, p):
+        return p.t0 <= c.t0 and c.t1 <= p.t1
+
+    chunk_t, chunk_n = {}, {}
+    for step in (s for s in spans if s.name == "server.step"):
+        rnd = [s for s in spans if s.name == "step.round" and inside(s, step)]
+        if not rnd:
+            continue                   # no round, so no event to compare
+        chunks = [s for s in spans
+                  if s.name == "prefill_chunk" and inside(s, step)]
+        chunk_t[rnd[0].tags["round"]] = sum(c.duration for c in chunks)
+        chunk_n[rnd[0].tags["round"]] = len(chunks)
+    events = srv.events.events()
+    assert sorted(chunk_t) == [ev.round for ev in events]
+    assert [chunk_n[ev.round] for ev in events] == \
+        [ev.prefill_chunks for ev in events]
+    t_prefill = sum(ev.t_prefill or 0.0 for ev in events)
+    assert t_prefill > 0
+    assert sum(chunk_t.values()) == pytest.approx(t_prefill, rel=0.05)
