@@ -5,21 +5,27 @@ allocation, so every row of a served batch must share a sequence budget.
 This module replaces that with vLLM-style paging:
 
   pool   = {
-    "k": [L, num_blocks, Kv, block_size, D],   # one block pool per layer stack
-    "v": [L, num_blocks, Kv, block_size, D],
+    "k": [L, num_blocks, block_size, Kv * D],  # one block pool per layer stack
+    "v": [L, num_blocks, block_size, Kv * D],
     "block_table": [B, max_blocks_per_row] int32,  # row -> pool block ids
     "index": [B] int32                             # committed tokens per row
   }
 
-Token at absolute position ``p`` of row ``b`` lives in
-``pool[block_table[b, p // block_size], :, p % block_size]``. The pool is
-head-major inside a block: one kv head of one block is a contiguous
-``[block_size, D]`` tile, which is the unit the Pallas kernels DMA (a TPU
-block's last two dims must be whole array dims or (8, 128)-aligned, so a
-single head cannot be sliced out of a token-major ``[block_size, Kv, D]``
-block). Rows own disjoint
-block sets handed out by the host-side ``BlockAllocator``; memory scales with
-the tokens actually resident, not ``batch * max(len)``.
+Token at absolute position ``p`` of row ``b`` in layer ``l`` lives in
+``pool[l, block_table[b, p // block_size], p % block_size]``: the pool is
+token-major, one token's kv heads side by side in one ``Kv * D`` row, and
+stored rank-4 so nothing reshapes it between the model and the kernels (on
+the TPU's tiled layouts such a reshape is a copy). A block is one
+``[block_size, Kv * D]`` tile whose two dims are whole array dims, so the
+Pallas kernels DMA it as it lies at every width and cut the heads out in
+VMEM; cutting ONE head out through the BlockSpec would be a ``D``-wide
+block of the minor dim, which the TPU takes only when ``D`` is a multiple
+of 128. The layer axis is part of every address: the model's layer
+scan carries the whole stack and writes each token's row in place at
+``(layer, block, offset)``, and the kernels take the layer index by scalar
+prefetch, so no layer's pool is ever sliced out or copied back. A head-
+sharded kernel (``kernels/ops.py``) splits the minor axis into ``Kv/n * D``
+pieces, whole heads each.
 
 Block 0 is the NULL block: unallocated table entries point at it, so writes
 from frozen/empty batch slots land somewhere harmless and gathers of
@@ -67,7 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.cache.kv_cache import _from_buf, _to_buf_dtype
+from repro.cache.kv_cache import _to_buf_dtype
 
 NULL_BLOCK = 0
 
@@ -76,7 +82,7 @@ def init_pool(num_layers, num_blocks, block_size, num_kv_heads, head_dim,
               dtype=jnp.bfloat16):
     """Per-layer-stack block pools (no table — tables are per cache, pools may
     be grouped, e.g. MoE sub-stacks sharing one table)."""
-    shape = (num_layers, num_blocks, num_kv_heads, block_size, head_dim)
+    shape = (num_layers, num_blocks, block_size, num_kv_heads * head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -94,22 +100,25 @@ def is_paged(cache) -> bool:
     return isinstance(cache, dict) and "block_table" in cache
 
 
-def write(layer_cache, k_new, v_new, block_table, index):
-    """Per-layer paged WRITE (pool update only — the write half of the
-    write/read split; ``models.attention.attn_paged`` is the read half).
+def write(pools, k_new, v_new, block_table, index, layer=0):
+    """Paged WRITE (pool update only — the write half of the write/read
+    split; ``models.attention.attn_paged`` is the read half).
 
-    layer_cache: {"k": [NB, Kv, BS, D], "v": ...} — this layer's pool slice.
-    k_new/v_new: [B, Q, Kv, D] written at positions index..index+Q-1 per row.
+    pools: {"k": [L, NB, BS, Kv*D], "v": ...} — a layer stack's pools (a
+    single layer's pool is a stack of one, ``layer`` 0).
+    k_new/v_new: [B, Q, Kv, D] written into layer ``layer`` at positions
+    index..index+Q-1 per row: one scatter of ``Kv*D`` rows per pool, in
+    place when the pools are a loop carry.
 
-    Returns the new layer cache. Deliberately does NOT return a gathered
-    per-row view: the old ``extend`` materialized ``[B, MB*BS, Kv, D]`` per
-    layer per step, so attention traffic scaled with worst-case row capacity
-    instead of live tokens. Readers scan blocks via the block table directly.
+    Returns the new pools. Deliberately does NOT return a gathered per-row
+    view: the old ``extend`` materialized ``[B, MB*BS, Kv, D]`` per layer per
+    step, so attention traffic scaled with worst-case row capacity instead of
+    live tokens. Readers scan blocks via the block table directly.
 
     Unlike the ring buffer, appends never evict: the write happens first and
     attention reads the post-write pool even for Q > 1.
     """
-    BS = layer_cache["k"].shape[2]
+    BS = pools["k"].shape[2]
     B, Q = k_new.shape[0], k_new.shape[1]
     MB = block_table.shape[1]
     idx = jnp.asarray(index)
@@ -122,10 +131,11 @@ def write(layer_cache, k_new, v_new, block_table, index):
     # the row's last table entry (NULL for released rows) instead of OOB
     blk = block_table[rows, jnp.minimum(pos // BS, MB - 1)]  # [B, Q]
     off = pos % BS
-    # advanced indices split by the head slice index as [B, Q, Kv, D]
-    k_buf = layer_cache["k"].at[blk, :, off].set(_to_buf_dtype(k_new, layer_cache["k"].dtype))
-    v_buf = layer_cache["v"].at[blk, :, off].set(_to_buf_dtype(v_new, layer_cache["v"].dtype))
-    return {"k": k_buf, "v": v_buf}
+
+    def put(pool, new):
+        rows_new = _to_buf_dtype(new.reshape(B, Q, -1), pool.dtype)
+        return pool.at[layer, blk, off].set(rows_new)
+    return {"k": put(pools["k"], k_new), "v": put(pools["v"], v_new)}
 
 
 def copy_blocks(cache, pairs):
@@ -150,19 +160,19 @@ def compact_positions(cache, block_table, src_pos, dst_pos):
     layers at once. The gather completes before the scatter, so overlapping
     src/dst are safe; the tree layout guarantees src >= dst per step (winner
     slots always sit at-or-beyond their committed destination)."""
-    BS = cache["k"].shape[3]
+    BS = cache["k"].shape[2]
     MB = block_table.shape[1]
     B = src_pos.shape[0]
     rows = jnp.arange(B, dtype=jnp.int32)[:, None]
     sblk = block_table[rows, jnp.minimum(src_pos // BS, MB - 1)]
     dblk = block_table[rows, jnp.minimum(dst_pos // BS, MB - 1)]
-    # advanced indices split by slices gather as [B, P, L, Kv, D]; the
-    # scatter below uses the same index form, so the shapes line up
-    k = cache["k"][:, sblk, :, src_pos % BS]
-    v = cache["v"][:, sblk, :, src_pos % BS]
+    # adjacent advanced indices gather as [L, B, P, Kv*D]; the scatter
+    # below uses the same index form, so the shapes line up
+    k = cache["k"][:, sblk, src_pos % BS]
+    v = cache["v"][:, sblk, src_pos % BS]
     out = dict(cache)
-    out["k"] = cache["k"].at[:, dblk, :, dst_pos % BS].set(k)
-    out["v"] = cache["v"].at[:, dblk, :, dst_pos % BS].set(v)
+    out["k"] = cache["k"].at[:, dblk, dst_pos % BS].set(k)
+    out["v"] = cache["v"].at[:, dblk, dst_pos % BS].set(v)
     return out
 
 

@@ -103,53 +103,57 @@ def flash_attention(q, k, v, *, bq=256, bs=512, window=None, causal=True):
     return out[:, :Sq]
 
 
-def paged_attention(q, k_pool, v_pool, block_table, index, *, window=None,
-                    max_live=None):
-    """Block-table-native paged attention (decode/verify path). Reads are
-    bounded by each row's live block count; the kernel resolves pool block
-    ids in-kernel from the prefetched table. int8 KV pools fall back to the
-    jnp oracle (the kernel reads float pools only)."""
+def paged_attention(q, k_pool, v_pool, block_table, index, *, layer=0,
+                    window=None, max_live=None):
+    """Block-table-native paged attention (decode/verify path) over layer
+    ``layer`` of a stacked ``[L, NB, BS, Kv*D]`` pool. Reads are bounded by
+    each row's live block count; the kernel resolves the layer and the pool
+    block ids in-kernel from prefetched scalars. int8 KV pools fall back to
+    the jnp oracle (the kernel reads float pools only)."""
     if k_pool.dtype == jnp.int8:
         from repro.models.attention import attn_paged
-        return attn_paged(q, k_pool, v_pool, block_table, index,
+        return attn_paged(q, k_pool, v_pool, block_table, index, layer=layer,
                           window=window, max_live=max_live)
 
-    def fn(q, k, v, tbl, idx, *ml):
-        return _pa.paged_flash_attention(q, k, v, tbl, idx, window=window,
+    def fn(q, k, v, tbl, idx, lyr, *ml):
+        return _pa.paged_flash_attention(q, k, v, tbl, idx, lyr,
+                                         window=window,
                                          interpret=_interpret(),
                                          max_live=ml[0] if ml else None)
     ml = () if max_live is None else (jnp.asarray(max_live, jnp.int32),)
-    return _per_device(fn, *_attn_specs(k_pool.shape[1], 2 + len(ml)))(
-        q, k_pool, v_pool, block_table, index, *ml)
+    return _per_device(fn, *_attn_specs(q, k_pool, 3 + len(ml)))(
+        q, k_pool, v_pool, block_table, index, jnp.asarray(layer, jnp.int32),
+        *ml)
 
 
-def _attn_specs(num_kv_heads, n_replicated):
-    """(in_specs, out_specs) of a paged kernel call: q/out [B, Q, H, D] and
-    pools [NB, Kv, BS, D] split on heads, then ``n_replicated`` small
-    operands (tables, indices, tree masks, bounds)."""
-    ax = _head_axes(num_kv_heads)
-    heads, pool = P(None, None, ax, None), P(None, ax, None, None)
+def _attn_specs(q, k_pool, n_replicated):
+    """(in_specs, out_specs) of a paged kernel call: q/out [B, Q, H, D] split
+    on heads and pools [L, NB, BS, Kv*D] on their minor axis, in whole-head
+    ``Kv/n * D`` pieces, then ``n_replicated`` small operands (tables,
+    indices, the layer, tree masks, bounds)."""
+    ax = _head_axes(k_pool.shape[3] // q.shape[3])
+    heads, pool = P(None, None, ax, None), P(None, None, None, ax)
     return (heads, pool, pool) + (P(),) * n_replicated, heads
 
 
 def tree_attention(q, k_pool, v_pool, block_table, index, depths, bits, *,
-                   window=None, max_live=None):
+                   layer=0, window=None, max_live=None):
     """Block-table-native tree-verify attention: one stacked pass scores all
     root-to-leaf paths of a speculation tree (depths/bits from core/tree.py).
     int8 KV pools fall back to the jnp oracle, mirroring paged_attention."""
     if k_pool.dtype == jnp.int8:
         from repro.models.attention import attn_tree
         return attn_tree(q, k_pool, v_pool, block_table, index, depths, bits,
-                         window=window, max_live=max_live)
+                         layer=layer, window=window, max_live=max_live)
 
-    def fn(q, k, v, tbl, idx, dep, bts, *ml):
-        return _ta.tree_flash_attention(q, k, v, tbl, idx, dep, bts,
+    def fn(q, k, v, tbl, idx, dep, bts, lyr, *ml):
+        return _ta.tree_flash_attention(q, k, v, tbl, idx, dep, bts, lyr,
                                         window=window, interpret=_interpret(),
                                         max_live=ml[0] if ml else None)
     ml = () if max_live is None else (jnp.asarray(max_live, jnp.int32),)
-    return _per_device(fn, *_attn_specs(k_pool.shape[1], 4 + len(ml)))(
+    return _per_device(fn, *_attn_specs(q, k_pool, 5 + len(ml)))(
         q, k_pool, v_pool, block_table, index, jnp.asarray(depths, jnp.int32),
-        jnp.asarray(bits, jnp.int32), *ml)
+        jnp.asarray(bits, jnp.int32), jnp.asarray(layer, jnp.int32), *ml)
 
 
 def ssd_scan(x, dA, Bm, Cm, *, chunk=128):

@@ -8,13 +8,23 @@ path built per layer per round.
 
 Structure (same skeleton as kernels/flash_attention.py):
 
-  * grid ``(B, Kv, max_blocks_per_row)`` with the KV-block axis innermost so
-    the running (max, denom, accum) persist in VMEM scratch across blocks;
-  * GQA folded into the q rows — each (batch, kv-head) program attends
-    ``Q * group`` query rows against that head's KV blocks;
-  * block-table indices resolved IN-KERNEL via scalar prefetch
-    (``PrefetchScalarGridSpec``): the k/v index maps read the prefetched
-    block table, so each grid step DMAs exactly one live pool block;
+  * grid ``(B, row tiles, max_blocks_per_row)`` with the KV-block axis
+    innermost so the running (max, denom, accum) of every kv head persist
+    in VMEM scratch across blocks;
+  * one grid step reads one whole ``[BS, Kv * D]`` block of the token-major
+    pool (cache/paged_kv.py) and runs all kv heads in its body, cutting
+    each head's ``D`` columns out in VMEM (a one-head BlockSpec would be a
+    ``D``-wide block of the minor dim, which the TPU takes only when ``D``
+    is a multiple of 128; the whole block is legal at every width);
+  * GQA folded into the q rows — each kv head attends ``Q * group`` query
+    rows against its columns of the block; a prefill chunk's thousands of
+    rows are cut into tiles of at most ``ROW_TILE`` (a middle grid axis, of
+    length 1 for decode), so the all-heads blocks stay inside VMEM;
+  * the pools are the whole layer stack ``[L, NB, BS, Kv * D]``; the layer
+    index and the block-table indices are resolved IN-KERNEL via scalar
+    prefetch (``PrefetchScalarGridSpec``): the k/v index maps read them, so
+    each grid step DMAs exactly one live block of one layer and no layer's
+    pool is ever sliced out of the stack;
   * dead steps (``j >= live_blocks[row]``) clamp the index map to the row's
     last live block — Pallas elides the re-fetch of an unchanged block — and
     skip their compute via ``pl.when``, so both traffic and FLOPs are bounded
@@ -33,66 +43,85 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+ROW_TILE = 256      # q rows (positions x group) a grid step holds per head
 
 
-def _kernel(tbl_ref, live_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, bs: int, gq: int, window,
+def init_scratch(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def attend_block(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, mask, scale):
+    """One online-softmax step of every kv head over one pool block: head
+    ``h`` attends its q rows ``q_ref[0, h]`` against the block's columns
+    ``h*D .. h*D+D``, cut out in VMEM. ``mask`` [R, bs] is shared by the
+    heads. Blocks: q [1, Kv, R, D]; k/v [1, 1, bs, Kv*D]; scratch m/l
+    [Kv, R, 1], acc [Kv, R, D]."""
+    Kv, D = q_ref.shape[1], q_ref.shape[3]
+    for h in range(Kv):
+        cols = pl.ds(h * D, D)
+        q = q_ref[0, h].astype(jnp.float32)                    # [R, D]
+        k = k_ref[0, 0, :, cols].astype(jnp.float32)           # [bs, D]
+        v = v_ref[0, 0, :, cols].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(mask, s * scale, NEG_INF)
+
+        m_prev = m_ref[h, :, 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, None])
+        l_ref[h, :, 0] = l_ref[h, :, 0] * alpha + jnp.sum(p, axis=1)
+        acc_ref[h] = acc_ref[h] * alpha[:, None] + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[h, :, 0] = m_new
+
+
+def emit(o_ref, l_ref, acc_ref):
+    denom = jnp.maximum(l_ref[:, :, 0], 1e-30)
+    o_ref[0] = (acc_ref[...] / denom[:, :, None]).astype(o_ref.dtype)
+
+
+def _kernel(tbl_ref, live_ref, idx_ref, layer_ref, q_ref, k_ref, v_ref,
+            o_ref, m_ref, l_ref, acc_ref, *, bs: int, gq: int, window,
             scale: float):
-    """Blocks: q/o [1, 1, R, D]; k/v [1, 1, bs, D] (R = padded Q*gq rows)."""
+    """Grid (row b, row tile t, block j); blocks as in ``attend_block``,
+    R = one tile of the padded Q*gq rows."""
     b = pl.program_id(0)
+    t = pl.program_id(1)
     j = pl.program_id(2)
-    n_j = pl.num_programs(2)
     R = q_ref.shape[2]
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    pl.when(j == 0)(lambda: init_scratch(m_ref, l_ref, acc_ref))
 
     @pl.when(j < live_ref[b])
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                    # [R, D]
-        k = k_ref[0, 0].astype(jnp.float32)                    # [bs, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-
         # rows are (q position, group); padded tail rows are sliced off by
         # the wrapper, their positions just run past the live length
-        r_iota = jax.lax.broadcasted_iota(jnp.int32, (R, bs), 0)
+        r_iota = t * R + jax.lax.broadcasted_iota(jnp.int32, (R, bs), 0)
         q_pos = idx_ref[b] + r_iota // gq
         kv_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (R, bs), 1)
         mask = q_pos >= kv_pos
         if window is not None:
             mask &= jnp.abs(q_pos - kv_pos) < window
-        s = jnp.where(mask, s, NEG_INF)
+        attend_block(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, mask, scale)
 
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[:, 0] = m_new
-
-    @pl.when(j == n_j - 1)
-    def _emit():
-        denom = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+    pl.when(j == pl.num_programs(2) - 1)(lambda: emit(o_ref, l_ref, acc_ref))
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
-def paged_flash_attention(q, k_pool, v_pool, block_table, index, *,
+def paged_flash_attention(q, k_pool, v_pool, block_table, index, layer=0, *,
                           window=None, interpret=False, max_live=None):
-    """q: [B, Q, H, D]; k_pool/v_pool: [NB, Kv, BS, D]; block_table: [B, MB];
-    index: [B] committed tokens per row (queries sit at index..index+Q-1,
-    already written into the pool). H = Kv * gq (GQA-aware). ``max_live``
-    caps every row's scanned blocks at ceil(max_live/BS), matching the
-    oracle's explicit-bound truncation semantics."""
+    """q: [B, Q, H, D]; k_pool/v_pool: [L, NB, BS, Kv*D]; block_table:
+    [B, MB]; index: [B] committed tokens per row (queries sit at
+    index..index+Q-1, already written into layer ``layer`` of the pool).
+    H = Kv * gq (GQA-aware). ``max_live`` caps every row's scanned blocks at
+    ceil(max_live/BS), matching the oracle's explicit-bound truncation
+    semantics. The kernel's result is ``[B, Kv, R, D]``, R = Q*gq padded to
+    a multiple of 8 (of ``ROW_TILE`` above it)."""
     B, Q, H, D = q.shape
-    Kv, BS = k_pool.shape[1], k_pool.shape[2]
+    BS, Kv = k_pool.shape[2], k_pool.shape[3] // D
     MB = block_table.shape[1]
     gq = H // Kv
     scale = D ** -0.5
@@ -104,30 +133,37 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, index, *,
         cap = jnp.clip((jnp.asarray(max_live, jnp.int32) + BS - 1) // BS,
                        1, MB).astype(jnp.int32)
         live = jnp.minimum(live, cap)
+    lyr = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
-    # rows = (q position, group); pad to a sublane multiple for the VPU tiles
+    # rows = (q position, group); pad to a sublane multiple for the VPU
+    # tiles, and to whole row tiles where there is more than one
     qr = q.reshape(B, Q, Kv, gq, D).transpose(0, 2, 1, 3, 4) \
           .reshape(B, Kv, Q * gq, D)
-    R = -(-(Q * gq) // 8) * 8
+    tile = ROW_TILE if Q * gq > ROW_TILE else 8
+    R = -(-(Q * gq) // tile) * tile
+    TR = min(R, ROW_TILE)
     if R != Q * gq:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, R - Q * gq), (0, 0)))
 
-    def _kv_map(b, h, j, tbl, live_b, _idx):
+    def _kv_map(b, t, j, tbl, live_b, _idx, lyr):
         jj = jnp.minimum(j, jnp.maximum(live_b[b] - 1, 0))
-        return (tbl[b, jj], h, 0, 0)
+        return (lyr[0], tbl[b, jj], 0, 0)
+
+    def _q_map(b, t, j, *_):
+        return (b, 0, t, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Kv, MB),
+        num_scalar_prefetch=4,
+        grid=(B, R // TR, MB),
         in_specs=[
-            pl.BlockSpec((1, 1, R, D), lambda b, h, j, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, BS, D), _kv_map),
-            pl.BlockSpec((1, 1, BS, D), _kv_map),
+            pl.BlockSpec((1, Kv, TR, D), _q_map),
+            pl.BlockSpec((1, 1, BS, Kv * D), _kv_map),
+            pl.BlockSpec((1, 1, BS, Kv * D), _kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, R, D), lambda b, h, j, *_: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((R, 1), jnp.float32),
-                        pltpu.VMEM((R, 1), jnp.float32),
-                        pltpu.VMEM((R, D), jnp.float32)],
+        out_specs=pl.BlockSpec((1, Kv, TR, D), _q_map),
+        scratch_shapes=[pltpu.VMEM((Kv, TR, 1), jnp.float32),
+                        pltpu.VMEM((Kv, TR, 1), jnp.float32),
+                        pltpu.VMEM((Kv, TR, D), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=BS, gq=gq, window=window, scale=scale),
@@ -135,6 +171,6 @@ def paged_flash_attention(q, k_pool, v_pool, block_table, index, *,
         out_shape=jax.ShapeDtypeStruct((B, Kv, R, D), q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(block_table.astype(jnp.int32), live, idx, qr, k_pool, v_pool)
+    )(block_table.astype(jnp.int32), live, idx, lyr, qr, k_pool, v_pool)
     return out[:, :, :Q * gq].reshape(B, Kv, Q, gq, D) \
               .transpose(0, 2, 1, 3, 4).reshape(B, Q, H, D)

@@ -26,21 +26,23 @@ def flash_attention_ref(q, k, v, *, window=None, causal=True):
     return attn_dense(q, k, v, q_pos, kv_pos, window=window, causal=causal)
 
 
-def paged_attention_ref(q, k_pool, v_pool, block_table, index, *,
+def paged_attention_ref(q, k_pool, v_pool, block_table, index, *, layer=0,
                         window=None):
     """Oracle via the model-level block-scan paged attention (itself
-    equivalence-tested against the dense gathered view)."""
+    equivalence-tested against the dense gathered view); pools are the
+    stacked ``[L, NB, BS, Kv*D]`` layout the kernel reads."""
     from repro.models.attention import attn_paged
-    return attn_paged(q, k_pool, v_pool, block_table, index, window=window)
+    return attn_paged(q, k_pool, v_pool, block_table, index, layer=layer,
+                      window=window)
 
 
 def tree_attention_ref(q, k_pool, v_pool, block_table, index, depths, bits,
-                       *, window=None):
+                       *, layer=0, window=None):
     """Oracle via the model-level block-scan tree attention (itself built on
     the equivalence-tested online-softmax step)."""
     from repro.models.attention import attn_tree
     return attn_tree(q, k_pool, v_pool, block_table, index, depths, bits,
-                     window=window)
+                     layer=layer, window=window)
 
 
 def ssd_scan_ref(x, dA, Bm, Cm, chunk=128):

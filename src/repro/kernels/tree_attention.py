@@ -8,10 +8,11 @@ contiguous pool positions ``index .. index+span-1`` (core/tree.py fixes the
 slot order; RoPE positions are ``index + depths[slot]``).
 
 Structure is the paged-decode kernel's (kernels/paged_attention.py): grid
-``(B, Kv, max_blocks_per_row)`` with KV blocks innermost, VMEM scratch
-carrying the online-softmax state, block ids resolved in-kernel from the
-prefetched table, dead steps clamped + skipped.  The only new ingredient is
-the mask:
+``(B, max_blocks_per_row)`` with KV blocks innermost, one whole
+``[BS, Kv * D]`` block of the stacked token-major pool per step with all kv
+heads in the body, VMEM scratch carrying each head's online-softmax state,
+the layer and the block ids resolved in-kernel from prefetched scalars, dead
+steps clamped + skipped.  The only new ingredient is the mask:
 
   * committed prefix (kv_pos < index): ordinary causal (+ window);
   * in-span KV slot t (rel = kv_pos - index in [0, span)): visible iff bit
@@ -34,32 +35,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.paged_attention import attend_block, emit, init_scratch
 
 
-def _kernel(tbl_ref, live_ref, idx_ref, q_ref, k_ref, v_ref, dep_ref,
-            bit_ref, o_ref, m_ref, l_ref, acc_ref, *, bs: int, span: int,
-            window, scale: float):
-    """Blocks: q/o [1, 1, R, D]; k/v [1, 1, bs, D]; dep/bit [R, 1]."""
+def _kernel(tbl_ref, live_ref, idx_ref, layer_ref, q_ref, k_ref, v_ref,
+            dep_ref, bit_ref, o_ref, m_ref, l_ref, acc_ref, *, bs: int,
+            span: int, window, scale: float):
+    """Grid (row b, block j); blocks as in the paged kernel's
+    ``attend_block``, plus dep/bit [R, 1]."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    n_j = pl.num_programs(2)
+    j = pl.program_id(1)
     R = q_ref.shape[2]
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    pl.when(j == 0)(lambda: init_scratch(m_ref, l_ref, acc_ref))
 
     @pl.when(j < live_ref[b])
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                    # [R, D]
-        k = k_ref[0, 0].astype(jnp.float32)                    # [bs, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-
         dep = dep_ref[:, 0]                                    # [R]
         bts = bit_ref[:, 0]                                    # [R]
         kv_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (R, bs), 1)
@@ -74,34 +65,24 @@ def _kernel(tbl_ref, live_ref, idx_ref, q_ref, k_ref, v_ref, dep_ref,
             jnp.broadcast_to(bts[:, None], (R, bs)),
             jnp.clip(rel, 0, 31)) & 1
         inspan = (rel >= 0) & (rel < span) & (bit > 0)
-        s = jnp.where(prefix | inspan, s, NEG_INF)
+        attend_block(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                     prefix | inspan, scale)
 
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[:, 0] = m_new
-
-    @pl.when(j == n_j - 1)
-    def _emit():
-        denom = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+    pl.when(j == pl.num_programs(1) - 1)(lambda: emit(o_ref, l_ref, acc_ref))
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def tree_flash_attention(q, k_pool, v_pool, block_table, index, depths,
-                         bits, *, window=None, interpret=False,
+                         bits, layer=0, *, window=None, interpret=False,
                          max_live=None):
-    """q: [B, span, H, D]; k_pool/v_pool: [NB, Kv, BS, D]; block_table:
+    """q: [B, span, H, D]; k_pool/v_pool: [L, NB, BS, Kv*D]; block_table:
     [B, MB]; index: [B] committed tokens per row (the root sits at index,
-    nodes at index+1..index+span-1, already written into the pool);
+    nodes at index+1..index+span-1, already written into layer ``layer`` of
+    the pool);
     depths/bits: int32 [span] per-slot depth and ancestor bitmask
     (core/tree.py). H = Kv * gq (GQA-aware)."""
     B, S, H, D = q.shape                                        # S = span
-    Kv, BS = k_pool.shape[1], k_pool.shape[2]
+    BS, Kv = k_pool.shape[2], k_pool.shape[3] // D
     MB = block_table.shape[1]
     gq = H // Kv
     scale = D ** -0.5
@@ -113,6 +94,7 @@ def tree_flash_attention(q, k_pool, v_pool, block_table, index, depths,
         cap = jnp.clip((jnp.asarray(max_live, jnp.int32) + BS - 1) // BS,
                        1, MB).astype(jnp.int32)
         live = jnp.minimum(live, cap)
+    lyr = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
     # rows = (slot, group); pad to a sublane multiple for the VPU tiles.
     # Padded tail rows get bits=0 (attend nothing in-span) and are sliced off.
@@ -139,24 +121,24 @@ def tree_flash_attention(q, k_pool, v_pool, block_table, index, depths,
     dep_rows = dep_rows[:, None]
     bit_rows = bit_rows[:, None]
 
-    def _kv_map(b, h, j, tbl, live_b, _idx):
+    def _kv_map(b, j, tbl, live_b, _idx, lyr):
         jj = jnp.minimum(j, jnp.maximum(live_b[b] - 1, 0))
-        return (tbl[b, jj], h, 0, 0)
+        return (lyr[0], tbl[b, jj], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Kv, MB),
+        num_scalar_prefetch=4,
+        grid=(B, MB),
         in_specs=[
-            pl.BlockSpec((1, 1, R, D), lambda b, h, j, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, BS, D), _kv_map),
-            pl.BlockSpec((1, 1, BS, D), _kv_map),
-            pl.BlockSpec((R, 1), lambda b, h, j, *_: (0, 0)),
-            pl.BlockSpec((R, 1), lambda b, h, j, *_: (0, 0)),
+            pl.BlockSpec((1, Kv, R, D), lambda b, j, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, BS, Kv * D), _kv_map),
+            pl.BlockSpec((1, 1, BS, Kv * D), _kv_map),
+            pl.BlockSpec((R, 1), lambda b, j, *_: (0, 0)),
+            pl.BlockSpec((R, 1), lambda b, j, *_: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, R, D), lambda b, h, j, *_: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((R, 1), jnp.float32),
-                        pltpu.VMEM((R, 1), jnp.float32),
-                        pltpu.VMEM((R, D), jnp.float32)],
+        out_specs=pl.BlockSpec((1, Kv, R, D), lambda b, j, *_: (b, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((Kv, R, 1), jnp.float32),
+                        pltpu.VMEM((Kv, R, 1), jnp.float32),
+                        pltpu.VMEM((Kv, R, D), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=BS, span=S, window=window, scale=scale),
@@ -164,7 +146,7 @@ def tree_flash_attention(q, k_pool, v_pool, block_table, index, depths,
         out_shape=jax.ShapeDtypeStruct((B, Kv, R, D), q.dtype),
         interpret=interpret,
         name="tree_attention",
-    )(block_table.astype(jnp.int32), live, idx, qr, k_pool, v_pool,
+    )(block_table.astype(jnp.int32), live, idx, lyr, qr, k_pool, v_pool,
       dep_rows, bit_rows)
     return out[:, :, :S * gq].reshape(B, Kv, S, gq, D) \
               .transpose(0, 2, 1, 3, 4).reshape(B, S, H, D)

@@ -142,20 +142,23 @@ def attention(q, k, v, q_pos, kv_pos, *, window=None, scale=None,
 
 
 # ------------------------------------------------------------- paged read path
-def _take_block(pool, blk, dtype):
-    """Gather pool block ``blk[b]`` for every row: [NB, Kv, BS, D] ->
-    [B, BS, Kv, D] token-major (the layout ``_online_step`` reads)."""
+def _take_block(pool, layer, blk, Kv, dtype):
+    """Gather layer ``layer``'s pool block ``blk[b]`` for every row:
+    [L, NB, BS, Kv*D] -> [B, BS, Kv, D] (the layout ``_online_step``
+    reads)."""
     from repro.cache.kv_cache import _from_buf
-    return _from_buf(jnp.take(pool, blk, axis=0), dtype).swapaxes(1, 2)
+    x = _from_buf(pool[layer, blk], dtype)                        # [B, BS, Kv*D]
+    return x.reshape(x.shape[:2] + (Kv, x.shape[-1] // Kv))
 
 
-def attn_paged(q, k_pool, v_pool, block_table, index, *, window=None,
-               scale=None, max_live=None, return_stats=False):
+def attn_paged(q, k_pool, v_pool, block_table, index, *, layer=0,
+               window=None, scale=None, max_live=None, return_stats=False):
     """Block-table-native attention over a paged KV pool (jnp oracle).
 
     q:            [B, Q, H, D] queries at absolute positions index..index+Q-1
                   (already written into the pool by ``paged_kv.write``).
-    k_pool/v_pool:[NB, Kv, BS, D] this layer's block pool, post-write.
+    k_pool/v_pool:[L, NB, BS, Kv*D] a layer stack's block pools, post-write
+                  (cache/paged_kv.py); ``layer`` picks the layer read.
     block_table:  [B, MB] int32 row -> pool block ids (NULL block = 0).
     index:        [B] (or scalar) committed tokens per row BEFORE this write.
     max_live:     optional live-token bound (max over rows of index+Q); when
@@ -172,7 +175,7 @@ def attn_paged(q, k_pool, v_pool, block_table, index, *, window=None,
     is carried through the actual loop, so tests can assert the traffic bound.
     """
     B, Q, H, D = q.shape
-    Kv, BS = k_pool.shape[1], k_pool.shape[2]
+    BS, Kv = k_pool.shape[2], k_pool.shape[3] // D
     MB = block_table.shape[1]
     G = H // Kv
     scale = scale if scale is not None else D ** -0.5
@@ -188,8 +191,8 @@ def attn_paged(q, k_pool, v_pool, block_table, index, *, window=None,
     def body(j, carry):
         softmax_carry, n_read = carry
         blk = jnp.take(block_table, j, axis=1)                    # [B]
-        k_j = _take_block(k_pool, blk, q.dtype)                   # [B, BS, Kv, D]
-        v_j = _take_block(v_pool, blk, q.dtype)
+        k_j = _take_block(k_pool, layer, blk, Kv, q.dtype)        # [B, BS, Kv, D]
+        v_j = _take_block(v_pool, layer, blk, Kv, q.dtype)
         kv_pos = j * BS + jnp.arange(BS, dtype=jnp.int32)         # [BS]
         softmax_carry = _online_step(softmax_carry, qf, k_j, v_j, q_pos,
                                      kv_pos, window, scale)
@@ -204,17 +207,18 @@ def attn_paged(q, k_pool, v_pool, block_table, index, *, window=None,
     return o
 
 
-def attention_paged(q, k_pool, v_pool, block_table, index, *, window=None,
-                    scale=None, max_live=None):
+def attention_paged(q, k_pool, v_pool, block_table, index, *, layer=0,
+                    window=None, scale=None, max_live=None):
     """Paged-attention dispatch: Pallas kernel on TPU (float pools), jnp
     oracle everywhere else (CPU, dry-run, int8 KV pools)."""
     if jax.default_backend() == "tpu" and k_pool.dtype != jnp.int8 \
             and scale is None:
         from repro.kernels import ops
         return ops.paged_attention(q, k_pool, v_pool, block_table, index,
-                                   window=window, max_live=max_live)
-    return attn_paged(q, k_pool, v_pool, block_table, index, window=window,
-                      scale=scale, max_live=max_live)
+                                   layer=layer, window=window,
+                                   max_live=max_live)
+    return attn_paged(q, k_pool, v_pool, block_table, index, layer=layer,
+                      window=window, scale=scale, max_live=max_live)
 
 
 # -------------------------------------------------------------- tree read path
@@ -269,14 +273,14 @@ def attn_tree_ring(q, k, v, index, depths, bits, *, window=None, scale=None):
 
 
 def attn_tree(q, k_pool, v_pool, block_table, index, depths, bits, *,
-              window=None, scale=None, max_live=None):
+              layer=0, window=None, scale=None, max_live=None):
     """Tree-verify attention over a paged block pool (jnp oracle).
 
     Same block-bounded online-softmax loop as ``attn_paged``, with the
     causal mask replaced by ``_tree_mask``: the span slots written at
     index..index+span-1 are only visible along each query's root path."""
     B, S, H, D = q.shape                                        # S = span
-    Kv, BS = k_pool.shape[1], k_pool.shape[2]
+    BS, Kv = k_pool.shape[2], k_pool.shape[3] // D
     MB = block_table.shape[1]
     G = H // Kv
     scale = scale if scale is not None else D ** -0.5
@@ -292,8 +296,8 @@ def attn_tree(q, k_pool, v_pool, block_table, index, depths, bits, *,
 
     def body(j, carry):
         blk = jnp.take(block_table, j, axis=1)                   # [B]
-        k_j = _take_block(k_pool, blk, q.dtype)
-        v_j = _take_block(v_pool, blk, q.dtype)
+        k_j = _take_block(k_pool, layer, blk, Kv, q.dtype)
+        v_j = _take_block(v_pool, layer, blk, Kv, q.dtype)
         kv_pos = j * BS + jnp.arange(BS, dtype=jnp.int32)
         m = _tree_mask(idx, kv_pos, depths, bits, window)
         return _online_step(carry, qf, k_j, v_j, q_pos, kv_pos, window,
@@ -305,14 +309,15 @@ def attn_tree(q, k_pool, v_pool, block_table, index, depths, bits, *,
 
 
 def attention_tree(q, k_pool, v_pool, block_table, index, depths, bits, *,
-                   window=None, scale=None, max_live=None):
+                   layer=0, window=None, scale=None, max_live=None):
     """Tree-attention dispatch: Pallas kernel on TPU (float pools), jnp
     oracle everywhere else (CPU, dry-run, int8 KV pools)."""
     if jax.default_backend() == "tpu" and k_pool.dtype != jnp.int8 \
             and scale is None:
         from repro.kernels import ops
         return ops.tree_attention(q, k_pool, v_pool, block_table, index,
-                                  depths, bits, window=window,
+                                  depths, bits, layer=layer, window=window,
                                   max_live=max_live)
     return attn_tree(q, k_pool, v_pool, block_table, index, depths, bits,
-                     window=window, scale=scale, max_live=max_live)
+                     layer=layer, window=window, scale=scale,
+                     max_live=max_live)

@@ -2,7 +2,9 @@
 
 Layers are stacked on a leading axis and executed with lax.scan so the compiled
 HLO contains one layer body regardless of depth (critical for the 40x2 dry-run
-compile budget). The KV cache is threaded through the scan as stacked xs/ys.
+compile budget). A ring KV cache is threaded through the scan as stacked
+xs/ys; a paged pool rides whole in the scan carry, with the layer index
+scanned, so each layer writes and reads it in place (``scan_pool``).
 
 API (used by every decoder family):
   init(cfg, rng)                                    -> params
@@ -64,7 +66,7 @@ def init(cfg, rng):
 
 # ------------------------------------------------------------------- forward
 def attn_block(cfg, p, x, q_pos, layer_cache, index, window, use_rope=True,
-               block_table=None, max_live=None, tree=None):
+               block_table=None, max_live=None, tree=None, layer=None):
     """Self-attention sub-block; returns (out, new_layer_cache or None).
     ``block_table`` non-None selects the paged-pool cache path: the pool
     write and the block-table-native read are split, so no gathered
@@ -74,7 +76,12 @@ def attn_block(cfg, p, x, q_pos, layer_cache, index, window, use_rope=True,
     ``tree`` = (depths, bits) int32 [Q] marks this as a stacked tree-verify
     pass (core/tree.py): q_pos already carries the depth offsets, the KV
     lands at contiguous slots index..index+Q-1, and visibility follows each
-    slot's ancestor bitmask instead of plain causality."""
+    slot's ancestor bitmask instead of plain causality.
+
+    On the paged path ``layer`` is the index into a stacked
+    ``[L, NB, BS, Kv*D]`` pool that is written and read in place; a caller
+    holding one layer's ``[NB, BS, Kv*D]`` slice passes ``layer=None`` and
+    the slice is used as a stack of one."""
     B, Q, _ = x.shape
     hd = cfg.head_dim
     h = L.rmsnorm(p["norm"], x, cfg.norm_eps)
@@ -89,14 +96,21 @@ def attn_block(cfg, p, x, q_pos, layer_cache, index, window, use_rope=True,
         o = attention(q, k, v, q_pos, kv_pos, window=window)
         new_cache = None
     elif block_table is not None:
-        new_cache = PAGED.write(layer_cache, k, v, block_table, index)
+        one = layer is None
+        pools = ({n: a[None] for n, a in layer_cache.items()} if one
+                 else layer_cache)
+        lyr = 0 if one else layer
+        new_cache = PAGED.write(pools, k, v, block_table, index, lyr)
         if tree is not None:
             o = attention_tree(q, new_cache["k"], new_cache["v"], block_table,
-                               index, tree[0], tree[1], window=window,
-                               max_live=max_live)
+                               index, tree[0], tree[1], layer=lyr,
+                               window=window, max_live=max_live)
         else:
             o = attention_paged(q, new_cache["k"], new_cache["v"], block_table,
-                                index, window=window, max_live=max_live)
+                                index, layer=lyr, window=window,
+                                max_live=max_live)
+        if one:
+            new_cache = {n: a[0] for n, a in new_cache.items()}
     else:
         k_all, v_all, kv_pos, new_cache = RING.write(layer_cache, k, v, index)
         if tree is not None:
@@ -113,10 +127,10 @@ def attn_block(cfg, p, x, q_pos, layer_cache, index, window, use_rope=True,
 
 
 def dense_layer(cfg, p, x, q_pos, layer_cache, index, block_table=None,
-                max_live=None, tree=None):
+                max_live=None, tree=None, layer=None):
     o, new_cache = attn_block(cfg, p["attn"], x, q_pos, layer_cache, index,
                               cfg.sliding_window, block_table=block_table,
-                              max_live=max_live, tree=tree)
+                              max_live=max_live, tree=tree, layer=layer)
     x = x + o
     x = x + L.swiglu(p["mlp"], L.rmsnorm(p["mlp_norm"], x, cfg.norm_eps))
     return x, new_cache
@@ -149,6 +163,23 @@ def scan_layers(layer_fn, stacked_params, x, cache, remat=False, cfg=None):
     return h, new_kv
 
 
+def scan_pool(layer_fn, stacked_params, x, pools, remat=False, cfg=None):
+    """Run layer_fn(params, h, pools, layer) over stacked params via
+    lax.scan with the whole paged pool stack in the carry and the layer index
+    as a scanned input: each layer updates the stack in place, so no layer's
+    pool is sliced out into xs or written back into a fresh stacked ys."""
+    def step(carry, xs):
+        lp, l = xs
+        h, kv = layer_fn(lp, *carry, l)
+        return (h, kv), None
+    if remat:
+        step = L.remat_wrap(step, cfg)
+    n = jax.tree_util.tree_leaves(stacked_params)[0].shape[0]
+    (h, kv), _ = jax.lax.scan(step, (x, pools),
+                              (stacked_params, jnp.arange(n, dtype=jnp.int32)))
+    return h, kv
+
+
 def forward(cfg, params, tokens, cache=None, *, input_embeds=None, logits_slice=None,
             max_live=None, tree=None):
     """tokens: [B, Q] int32 (or input_embeds [B, Q, D]).
@@ -173,12 +204,17 @@ def forward(cfg, params, tokens, cache=None, *, input_embeds=None, logits_slice=
     q_pos = jnp.asarray(index)[..., None] + offs \
         if jnp.asarray(index).ndim else index + offs
 
-    def layer_fn(lp, h, lc):
+    def layer_fn(lp, h, lc, layer=None):
         return dense_layer(cfg, lp, h, q_pos, lc, index, block_table,
-                           max_live, tree)
+                           max_live, tree, layer)
 
-    x, new_kv = scan_layers(layer_fn, params["layers"], x, cache,
-                            remat=cfg.remat, cfg=cfg)
+    if block_table is not None:
+        x, new_kv = scan_pool(layer_fn, params["layers"], x,
+                              {"k": cache["k"], "v": cache["v"]},
+                              remat=cfg.remat, cfg=cfg)
+    else:
+        x, new_kv = scan_layers(layer_fn, params["layers"], x, cache,
+                                remat=cfg.remat, cfg=cfg)
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if logits_slice == "last":
         x = x[:, -1:]

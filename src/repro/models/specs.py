@@ -165,18 +165,20 @@ def cache_specs(cfg, cache_shape, pol: ShardingPolicy, batch: int,
                 shard_seq: bool = True):
     """KV/state caches: batch on data when divisible.
 
-    Rank-5 KV leaves additionally shard axis 2 on the model axis when
-    divisible. For ring buffers [L, B, W, Kv, D] that is the sequence axis W
+    KV leaves additionally shard one axis on the model axis when divisible.
+    For ring buffers [L, B, W, Kv, D] that is the sequence axis W
     (sequence-parallel cache): attention over the cache becomes a sharded
     contraction that GSPMD resolves with partial softmax terms + a small
-    all-reduce. For paged pools [L, NB, Kv, BS, D] it is the kv-head axis,
-    the split the Pallas paged kernels run under ``shard_map``
+    all-reduce. For paged pools [L, NB, BS, Kv*D] (token-major, see
+    cache/paged_kv.py) it is the minor axis, cut into whole-head ``Kv/n*D``
+    pieces: the split the Pallas paged kernels run under ``shard_map``
     (kernels/ops.py) alongside the head-sharded q projection. Either way
     the cache — the dominant serving tensor — shrinks by the model-axis size
     per device.
     """
     b_ax = pol.batch_axis(batch)
     m_size = pol.axis_size(pol.model)
+    paged = isinstance(cache_shape, dict) and "block_table" in cache_shape
 
     def rule(path, leaf):
         rank = len(leaf.shape)
@@ -190,21 +192,25 @@ def cache_specs(cfg, cache_shape, pol: ShardingPolicy, batch: int,
         if bdim < rank and leaf.shape[bdim] == batch:
             spec[bdim] = b_ax
         key = _path_str(path).split("/")[-1]
-        if shard_seq and key in ("k", "v") and rank == 5 and m_size > 1:
+        if paged and rank == 4:          # pool: split whole kv heads
+            ax, n = 3, leaf.shape[3] // cfg.head_dim
+        else:                            # ring: split the sequence axis
+            ax, n = 2, leaf.shape[2] if rank == 5 else 0
+        if shard_seq and key in ("k", "v") and n and m_size > 1:
             if b_ax is None:
-                # batch replicated (2D-TP serving): spread W over EVERY axis
+                # batch replicated (2D-TP serving): spread over EVERY axis
                 d_names = (() if pol.data is None else
                            ((pol.data,) if isinstance(pol.data, str) else tuple(pol.data)))
                 m_names = ((pol.model,) if isinstance(pol.model, str)
                            else tuple(pol.model))
                 full = d_names + m_names
                 sz = pol.axis_size(pol.data) * m_size
-                if leaf.shape[2] % sz == 0:
-                    spec[2] = full
-                elif leaf.shape[2] % m_size == 0:
-                    spec[2] = pol.model
-            elif leaf.shape[2] % m_size == 0:
-                spec[2] = pol.model
+                if n % sz == 0:
+                    spec[ax] = full
+                elif n % m_size == 0:
+                    spec[ax] = pol.model
+            elif n % m_size == 0:
+                spec[ax] = pol.model
         return P(*spec)
 
     return jax.tree_util.tree_map_with_path(rule, cache_shape)

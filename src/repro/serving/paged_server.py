@@ -731,9 +731,9 @@ class PagedSpecServer:
         def per_block(cache):
             total = 0
             for leaf in jax.tree_util.tree_leaves(cache or {}):
-                if getattr(leaf, "ndim", 0) == 5:  # [L, NB, Kv, BS, D] pools
-                    L, _, Kv, BS, D = leaf.shape
-                    total += L * BS * Kv * D * jnp.dtype(leaf.dtype).itemsize
+                if getattr(leaf, "ndim", 0) == 4:  # [L, NB, BS, Kv*D] pools
+                    L, _, BS, row = leaf.shape
+                    total += L * BS * row * jnp.dtype(leaf.dtype).itemsize
             return total
 
         pt = per_block(self._state.tcache) if self._state is not None else 0

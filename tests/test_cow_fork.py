@@ -72,8 +72,8 @@ def test_fork_declines_under_pressure():
 
 def _pool(L, NB, BS, Kv, D, seed=0):
     k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
-    return {"k": jax.random.normal(k1, (L, NB, Kv, BS, D), jnp.float32),
-            "v": jax.random.normal(k2, (L, NB, Kv, BS, D), jnp.float32)}
+    return {"k": jax.random.normal(k1, (L, NB, BS, Kv * D), jnp.float32),
+            "v": jax.random.normal(k2, (L, NB, BS, Kv * D), jnp.float32)}
 
 
 def test_copy_blocks_duplicates_pool_blocks():
@@ -104,11 +104,8 @@ def test_branch_writes_are_isolated():
     prefix_k = jax.random.normal(jax.random.PRNGKey(3),
                                  (B, n_committed, Kv, D), jnp.float32)
     for layer in range(L):
-        lc = {"k": cache["k"][layer], "v": cache["v"][layer]}
-        lc = paged_kv.write(lc, prefix_k, prefix_k, a.device_table(),
-                            jnp.zeros((B,), jnp.int32))
-        cache["k"] = cache["k"].at[layer].set(lc["k"])
-        cache["v"] = cache["v"].at[layer].set(lc["v"])
+        cache = paged_kv.write(cache, prefix_k, prefix_k, a.device_table(),
+                               jnp.zeros((B,), jnp.int32), layer)
     pairs = a.fork_row(0, n_committed, 2)
     assert pairs is not None
     for w in range(2):
@@ -119,15 +116,13 @@ def test_branch_writes_are_isolated():
     for w in range(2):
         val = jnp.full((B, 3, Kv, D), float(w + 1), jnp.float32)
         for layer in range(L):
-            lc = {"k": cache["k"][layer], "v": cache["v"][layer]}
-            lc = paged_kv.write(lc, val, val, tbls[w:w + 1],
-                                jnp.full((B,), n_committed, jnp.int32))
-            cache["k"] = cache["k"].at[layer].set(lc["k"])
-            cache["v"] = cache["v"].at[layer].set(lc["v"])
+            cache = paged_kv.write(cache, val, val, tbls[w:w + 1],
+                                   jnp.full((B,), n_committed, jnp.int32),
+                                   layer)
 
     def read(table, pos):
         blk = table[pos // BS]
-        return np.asarray(cache["k"][:, blk, :, pos % BS])
+        return np.asarray(cache["k"][:, blk, pos % BS])
 
     for w in range(2):
         for p in range(n_committed):             # shared prefix intact
@@ -158,19 +153,17 @@ def test_compact_positions_paged_and_ring_agree():
     a = BlockAllocator(32, BS, MB, B)
     for b in range(B):
         assert a.ensure(b, n + 5)
-    paged = {"k": jnp.zeros((L, 32, Kv, BS, D), jnp.float32),
-             "v": jnp.zeros((L, 32, Kv, BS, D), jnp.float32),
+    paged = {"k": jnp.zeros((L, 32, BS, Kv * D), jnp.float32),
+             "v": jnp.zeros((L, 32, BS, Kv * D), jnp.float32),
              "block_table": a.device_table(),
              "index": jnp.full((B,), n, jnp.int32)}
     ring = {"k": jnp.zeros((L, B, W, Kv, D), jnp.float32),
             "v": jnp.zeros((L, B, W, Kv, D), jnp.float32),
             "index": jnp.zeros((), jnp.int32)}
     for layer in range(L):
-        lc = {"k": paged["k"][layer], "v": paged["v"][layer]}
-        lc = paged_kv.write(lc, dense[:, :n + 5], dense[:, :n + 5],
-                            paged["block_table"], jnp.zeros((B,), jnp.int32))
-        paged["k"] = paged["k"].at[layer].set(lc["k"])
-        paged["v"] = paged["v"].at[layer].set(lc["v"])
+        paged.update(paged_kv.write(paged, dense[:, :n + 5], dense[:, :n + 5],
+                                    paged["block_table"],
+                                    jnp.zeros((B,), jnp.int32), layer))
         kb, vb = kv_cache.write(ring["k"][layer], ring["v"][layer],
                                 dense[:, :n + 5], dense[:, :n + 5],
                                 jnp.zeros((), jnp.int32))
@@ -183,7 +176,7 @@ def test_compact_positions_paged_and_ring_agree():
     outr = RING.compact(ring, src, dst)
     rows = jnp.arange(B)[:, None]
     blk = outp["block_table"][rows, dst // BS]
-    got_p = np.moveaxis(np.asarray(outp["k"][:, blk, :, dst % BS]), 2, 0)
+    got_p = np.asarray(outp["k"][:, blk, dst % BS]).reshape(L, B, 3, Kv, D)
     got_r = np.asarray(outr["k"][:, rows, dst % W])
     want = np.asarray(dense[:, [n + 1, n + 3, n + 4]])   # [B, 3, Kv, D]
     for layer in range(L):

@@ -12,9 +12,11 @@ from repro.kernels import ops, ref
 from repro.models.attention import attn_dense, attn_paged
 
 def _pool_cache(key, B, n_tokens, BS, MB, Kv, D, num_blocks=None,
-                dtype=jnp.float32):
-    """Build a single-layer pool holding ``n_tokens[b]`` KV tokens per row
-    (written via paged_kv.write), plus the dense [B, S, Kv, D] mirror."""
+                dtype=jnp.float32, L=1, layer=0):
+    """Build an ``L``-layer pool stack whose layer ``layer`` holds
+    ``n_tokens[b]`` KV tokens per row (written via paged_kv.write), plus the
+    dense [B, S, Kv, D] mirror. Every other layer holds other values at the
+    same slots, so a reader of the wrong layer disagrees."""
     NB = num_blocks or (B * MB + 1)
     alloc = BlockAllocator(NB, BS, MB, B)
     S = max(n_tokens)
@@ -24,11 +26,12 @@ def _pool_cache(key, B, n_tokens, BS, MB, Kv, D, num_blocks=None,
     kk, kv_ = jax.random.split(key)
     k_dense = jax.random.normal(kk, (B, S, Kv, D), jnp.float32)
     v_dense = jax.random.normal(kv_, (B, S, Kv, D), jnp.float32)
-    layer = {"k": jnp.zeros((NB, Kv, BS, D), dtype),
-             "v": jnp.zeros((NB, Kv, BS, D), dtype)}
-    layer = paged_kv.write(layer, k_dense, v_dense, table,
-                           jnp.zeros((B,), jnp.int32))
-    return layer, table, k_dense, v_dense
+    pools = paged_kv.init_pool(L, NB, BS, Kv, D, dtype)
+    start = jnp.zeros((B,), jnp.int32)
+    for l in range(L):
+        k_l, v_l = (k_dense, v_dense) if l == layer else (-k_dense, v_dense + 1)
+        pools = paged_kv.write(pools, k_l, v_l, table, start, l)
+    return pools, table, k_dense, v_dense
 
 
 def _dense_ref(q, k_dense, v_dense, index, window=None):
@@ -43,15 +46,16 @@ def _dense_ref(q, k_dense, v_dense, index, window=None):
 
 @pytest.mark.parametrize("BS,MB", [(4, 8), (8, 4), (16, 2), (3, 9)])
 @pytest.mark.parametrize("H,Kv", [(4, 4), (8, 2), (6, 1)])
-def test_oracle_matches_dense_blocksizes_gqa(BS, MB, H, Kv):
+@pytest.mark.parametrize("L,lyr", [(1, 0), (3, 2)])
+def test_oracle_matches_dense_blocksizes_gqa(BS, MB, H, Kv, L, lyr):
     B, Q, D = 3, 4, 16
     n_tokens = [10, 17, 6]                      # ragged committed lengths
     key = jax.random.PRNGKey(0)
     layer, table, k_dense, v_dense = _pool_cache(key, B, [n + Q for n in n_tokens],
-                                                 BS, MB, Kv, D)
+                                                 BS, MB, Kv, D, L=L, layer=lyr)
     q = jax.random.normal(jax.random.PRNGKey(1), (B, Q, H, D), jnp.float32)
     index = jnp.asarray(n_tokens, jnp.int32)
-    got = attn_paged(q, layer["k"], layer["v"], table, index)
+    got = attn_paged(q, layer["k"], layer["v"], table, index, layer=lyr)
     S = max(n_tokens) + Q
     want = _dense_ref(q, k_dense[:, :S], v_dense[:, :S], index)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -59,23 +63,26 @@ def test_oracle_matches_dense_blocksizes_gqa(BS, MB, H, Kv):
 
 
 def test_pool_is_head_major():
-    """Token p, kv head h of row b sits at pool[table[b, p // BS], h,
-    p % BS]: one head of one block is a contiguous [BS, D] tile, the unit
-    the TPU kernels copy."""
-    B, BS, MB, Kv, D = 2, 4, 4, 3, 8
+    """The pool layout: token p of row b in layer l sits at pool[l,
+    table[b, p // BS], p % BS], its kv heads side by side in one Kv*D row
+    (head h in columns h*D..h*D+D), so one block is a contiguous
+    [BS, Kv*D] tile, the unit the TPU kernels copy."""
+    B, BS, MB, Kv, D, L = 2, 4, 4, 3, 8, 2
     layer, table, k_dense, _ = _pool_cache(jax.random.PRNGKey(7), B, [11, 6],
-                                           BS, MB, Kv, D)
-    assert layer["k"].shape == (B * MB + 1, Kv, BS, D)
+                                           BS, MB, Kv, D, L=L, layer=1)
+    assert layer["k"].shape == (L, B * MB + 1, BS, Kv * D)
     pool, tbl = np.asarray(layer["k"]), np.asarray(table)
     for b, n in enumerate([11, 6]):
         for p in range(n):
-            np.testing.assert_array_equal(pool[tbl[b, p // BS], :, p % BS],
-                                          np.asarray(k_dense[b, p]))
+            for h in range(Kv):
+                np.testing.assert_array_equal(
+                    pool[1, tbl[b, p // BS], p % BS, h * D:(h + 1) * D],
+                    np.asarray(k_dense[b, p, h]))
 
 
 def test_oracle_int8_pool_matches_dense_dequantized():
     """int8 pools (fixed-scale KV) take the oracle path on every backend;
-    it reads the head-major int8 blocks and matches dense attention over
+    it reads the token-major int8 blocks and matches dense attention over
     the dequantized values."""
     from repro.cache.kv_cache import _from_buf
     B, Q, H, Kv, D, BS, MB = 2, 3, 4, 2, 8, 4, 6
@@ -89,8 +96,8 @@ def test_oracle_int8_pool_matches_dense_dequantized():
     S = max(n_tokens) + Q
     tbl = np.asarray(table)
     deq = lambda pool: jnp.stack([
-        _from_buf(pool[tbl[b, np.arange(S) // BS], :, np.arange(S) % BS],
-                  jnp.float32) for b in range(B)])       # [B, S, Kv, D]
+        _from_buf(pool[0, tbl[b, np.arange(S) // BS], np.arange(S) % BS],
+                  jnp.float32).reshape(S, Kv, D) for b in range(B)])
     want = _dense_ref(q, deq(layer["k"]), deq(layer["v"]), index)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -174,18 +181,48 @@ def test_explicit_max_live_bound_is_honored():
 @pytest.mark.parametrize("BS,MB", [(8, 4), (4, 8), (16, 2)])
 @pytest.mark.parametrize("H,Kv,window", [(4, 4, None), (8, 2, None),
                                          (8, 2, 7), (4, 1, None)])
-def test_kernel_matches_oracle(BS, MB, H, Kv, window):
+@pytest.mark.parametrize("L,lyr", [(1, 0), (3, 1)])
+def test_kernel_matches_oracle(BS, MB, H, Kv, window, L, lyr):
+    """The kernel (interpret mode) against the jnp oracle and the float32
+    dense reference, reading one layer of a stack by its index."""
     B, Q, D = 3, 3, 32
     n_tokens = [13, 21, 5]
-    layer, table, _, _ = _pool_cache(jax.random.PRNGKey(10), B,
-                                     [n + Q for n in n_tokens], BS, MB, Kv, D)
+    layer, table, k_dense, v_dense = _pool_cache(
+        jax.random.PRNGKey(10), B, [n + Q for n in n_tokens], BS, MB, Kv, D,
+        L=L, layer=lyr)
     q = jax.random.normal(jax.random.PRNGKey(11), (B, Q, H, D), jnp.float32)
     index = jnp.asarray(n_tokens, jnp.int32)
     got = ops.paged_attention(q, layer["k"], layer["v"], table, index,
-                              window=window)
+                              layer=lyr, window=window)
     want = ref.paged_attention_ref(q, layer["k"], layer["v"], table, index,
-                                   window=window)
+                                   layer=lyr, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    S = max(n_tokens) + Q
+    dense = _dense_ref(q, k_dense[:, :S], v_dense[:, :S], index,
+                       window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("index", [0, 19])
+def test_kernel_prefill_chunk_row_tiles(index):
+    """A prefill chunk's Q*group rows (280 here) run as several row tiles of
+    the kernel's grid; the result matches the oracle and dense attention."""
+    from repro.kernels.paged_attention import ROW_TILE
+    B, Q, H, Kv, D, BS, MB = 1, 70, 8, 2, 16, 8, 12
+    assert Q * H // Kv > ROW_TILE
+    layer, table, k_dense, v_dense = _pool_cache(
+        jax.random.PRNGKey(16), B, [index + Q], BS, MB, Kv, D, L=2, layer=1)
+    q = jax.random.normal(jax.random.PRNGKey(17), (B, Q, H, D), jnp.float32)
+    idx = jnp.asarray([index], jnp.int32)
+    got = ops.paged_attention(q, layer["k"], layer["v"], table, idx, layer=1)
+    want = ref.paged_attention_ref(q, layer["k"], layer["v"], table, idx,
+                                   layer=1)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    dense = _dense_ref(q, k_dense, v_dense, idx)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
                                rtol=2e-5, atol=2e-5)
 
 

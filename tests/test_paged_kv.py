@@ -27,11 +27,34 @@ def _paged_cache(m, B, num_blocks=32, block_size=4, max_blocks=8, n_tokens=24):
     return {**cache, "block_table": alloc.device_table()}, alloc
 
 
+def _assert_same_kv(ring, paged, n):
+    """Every layer's K/V of positions 0..n-1: the ring's slot p against the
+    paged pool's token row at (layer, table[b, p // BS], p % BS)."""
+    def kv_leaves(cache):
+        flat = jax.tree_util.tree_flatten_with_path(cache)[0]
+        return [x for path, x in flat
+                if getattr(path[-1], "key", None) in ("k", "v")]
+    tbl = np.asarray(paged["block_table"])
+    pos = np.arange(n)
+    pairs = list(zip(kv_leaves(ring), kv_leaves(paged)))
+    assert pairs and len(kv_leaves(ring)) == len(kv_leaves(paged))
+    for r, pl_ in pairs:
+        L, B, _, Kv, D = r.shape
+        BS = pl_.shape[2]
+        for b in range(B):
+            got = np.asarray(pl_)[:, tbl[b, pos // BS], pos % BS]
+            np.testing.assert_allclose(got.reshape(L, n, Kv, D),
+                                       np.asarray(r)[:, b, :n], atol=2e-4)
+
+
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "mixtral-8x7b", "internvl2-26b"])
 def test_paged_matches_ring_logits(arch):
-    """Same token stream through ring and paged caches -> same logits, at
-    every phase: multi-token prefill, single-token decode, multi-token
-    (speculative-verify-shaped) extension."""
+    """Same token stream through ring and paged caches -> same logits and
+    the same K/V written for every layer, at every phase: multi-token
+    prefill, single-token decode, multi-token (speculative-verify-shaped)
+    extension. The dense paged path carries the whole pool stack through
+    its layer scan; the MoE path passes per-layer slices as stacks of
+    one."""
     m, p, cfg = _model(arch)
     B, P, G = 2, 6, 3
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, P), 0, cfg.vocab_size)
@@ -41,17 +64,20 @@ def test_paged_matches_ring_logits(arch):
     lr, ring, _ = m.apply(p, toks, ring)
     lp, paged, _ = m.apply(p, toks, paged)
     np.testing.assert_allclose(np.asarray(lr), np.asarray(lp), atol=2e-4)
+    _assert_same_kv(ring, paged, P)
 
     nxt = jnp.argmax(lr[:, -1], -1)[:, None]
     lr, ring, _ = m.apply(p, nxt, ring)            # decode fast-path (Q=1)
     lp, paged, _ = m.apply(p, nxt, paged)
     np.testing.assert_allclose(np.asarray(lr), np.asarray(lp), atol=2e-4)
+    _assert_same_kv(ring, paged, P + 1)
 
     multi = jax.random.randint(jax.random.PRNGKey(2), (B, G + 1), 0,
                                cfg.vocab_size)
     lr, ring, _ = m.apply(p, multi, ring)          # verify-shaped Q>1 extend
     lp, paged, _ = m.apply(p, multi, paged)
     np.testing.assert_allclose(np.asarray(lr), np.asarray(lp), atol=2e-4)
+    _assert_same_kv(ring, paged, P + G + 2)
 
 
 def test_paged_rollback_then_reextend_matches_ring():

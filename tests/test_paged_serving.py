@@ -59,6 +59,28 @@ def test_ragged_requests_match_own_greedy(arch):
     assert s["alpha_hat"] is not None
 
 
+def test_kv_traffic_charges_pool_block_bytes():
+    """``kv_traffic`` charges each block read at one block's bytes of every
+    layer of its pool stack, K and V: [L, NB, BS, Kv*D] pools."""
+    mt, md, pt, pd, cfg = _pair("llama3.2-1b")
+    rng = np.random.default_rng(1)
+    scfg = SchedulerConfig(max_batch=2, block_size=4, num_blocks=32,
+                           max_blocks_per_row=8, gamma_max=4,
+                           prefill_buckets=(8, 16))
+    srv = PagedSpecServer(mt, md, pt, pd, scfg)
+    for i, (P, new) in enumerate(RAGGED[:3]):
+        srv.submit(ServeRequest(i, rng.integers(0, cfg.vocab_size, P), new))
+    srv.run()
+    kv = srv.kv_traffic()
+
+    def block_bytes(c):
+        return 2 * c.num_layers * 4 * c.num_kv_heads * c.head_dim \
+            * jnp.dtype(c.act_dtype).itemsize
+    assert srv.kv_blocks_read_t > 0
+    assert kv["read_bytes"] == (srv.kv_blocks_read_t * block_bytes(mt.cfg)
+                                + srv.kv_blocks_read_d * block_bytes(md.cfg))
+
+
 def test_ar_fallback_when_cost_model_says_no():
     """c >= alpha makes speculation infeasible (paper §II-B): the scheduler
     must choose gamma*=0 and the server must serve exact AR anyway."""

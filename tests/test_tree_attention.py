@@ -23,7 +23,10 @@ SHAPES = {
 }
 
 
-def _pool_cache(key, B, n_tokens, BS, MB, Kv, D, dtype=jnp.float32):
+def _pool_cache(key, B, n_tokens, BS, MB, Kv, D, dtype=jnp.float32, L=1,
+                layer=0):
+    """An ``L``-layer pool stack whose layer ``layer`` holds the dense K/V
+    (every other layer holds other values at the same slots)."""
     NB = B * MB + 1
     alloc = BlockAllocator(NB, BS, MB, B)
     S = max(n_tokens)
@@ -33,19 +36,22 @@ def _pool_cache(key, B, n_tokens, BS, MB, Kv, D, dtype=jnp.float32):
     kk, kv_ = jax.random.split(key)
     k_dense = jax.random.normal(kk, (B, S, Kv, D), jnp.float32)
     v_dense = jax.random.normal(kv_, (B, S, Kv, D), jnp.float32)
-    layer = {"k": jnp.zeros((NB, Kv, BS, D), dtype),
-             "v": jnp.zeros((NB, Kv, BS, D), dtype)}
-    layer = paged_kv.write(layer, k_dense, v_dense, table,
-                           jnp.zeros((B,), jnp.int32))
-    return layer, table, k_dense, v_dense
+    pools = paged_kv.init_pool(L, NB, BS, Kv, D, dtype)
+    start = jnp.zeros((B,), jnp.int32)
+    for l in range(L):
+        k_l, v_l = (k_dense, v_dense) if l == layer else (-k_dense, v_dense + 1)
+        pools = paged_kv.write(pools, k_l, v_l, table, start, l)
+    return pools, table, k_dense, v_dense
 
 
-def _setup(shape, B, H, Kv, D, BS, MB, roots, seed=0, dtype=jnp.float32):
+def _setup(shape, B, H, Kv, D, BS, MB, roots, seed=0, dtype=jnp.float32,
+           L=1, layer=0):
     span = shape.span
     idx = jnp.asarray(roots, jnp.int32)                 # root positions
     n_tokens = [r + span for r in roots]
     layer, table, k_dense, v_dense = _pool_cache(
-        jax.random.PRNGKey(seed), B, n_tokens, BS, MB, Kv, D, dtype=dtype)
+        jax.random.PRNGKey(seed), B, n_tokens, BS, MB, Kv, D, dtype=dtype,
+        L=L, layer=layer)
     q = jax.random.normal(jax.random.PRNGKey(seed + 1), (B, span, H, D),
                           jnp.float32).astype(dtype)
     depths = jnp.asarray(shape.depths)
@@ -102,16 +108,25 @@ def test_sibling_branches_do_not_leak():
 @pytest.mark.parametrize("name", sorted(SHAPES))
 @pytest.mark.parametrize("BS,MB,H,Kv", [(4, 8, 4, 4), (8, 4, 8, 2),
                                         (16, 2, 4, 1)])
-def test_kernel_matches_oracle(name, BS, MB, H, Kv):
+@pytest.mark.parametrize("L,lyr", [(1, 0), (2, 1)])
+def test_kernel_matches_oracle(name, BS, MB, H, Kv, L, lyr):
+    """The kernel (interpret mode) against the jnp oracle and the float32
+    dense ring-path mask, reading one layer of a stack by its index."""
     shape = SHAPES[name]
     B, D = 3, 32
-    layer, table, _, _, q, idx, depths, bits = _setup(
-        shape, B, H, Kv, D, BS, MB, roots=[11, 19, 3], seed=20)
+    layer, table, k_dense, v_dense, q, idx, depths, bits = _setup(
+        shape, B, H, Kv, D, BS, MB, roots=[11, 19, 3], seed=20, L=L,
+        layer=lyr)
     got = ops.tree_attention(q, layer["k"], layer["v"], table, idx,
-                             depths, bits)
+                             depths, bits, layer=lyr)
     want = ref.tree_attention_ref(q, layer["k"], layer["v"], table, idx,
-                                  depths, bits)
+                                  depths, bits, layer=lyr)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    S = int(jnp.max(idx)) + shape.span
+    ring = attn_tree_ring(q, k_dense[:, :S], v_dense[:, :S], idx, depths,
+                          bits)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ring),
                                rtol=2e-5, atol=2e-5)
 
 
