@@ -10,6 +10,8 @@ import time
 import jax
 import jax.numpy as jnp
 
+from bench.families import family
+
 
 def token_altered(served, cfg: dict) -> None:
     """The newest committed token of every live row is changed where the
@@ -44,19 +46,25 @@ def state_unchanged(served, cfg: dict) -> None:
 
 def skip_late_layers(served, cfg: dict) -> None:
     """The target's layers past the drafter's depth add nothing to the
-    residual stream (their ``attn.o`` and ``mlp.down`` zeroed, in place):
-    every program of the served path then serves what the drafter's own
-    layers give."""
+    residual stream (the leaves through which they write to it, the
+    family's ``late_writers``, zeroed in place): every program of the served
+    path then serves what the drafter's own layers give."""
     k = cfg["self_draft"]["layers"]
     srv = served.srv
     zero = jax.jit(lambda w: w.at[k:].set(0), donate_argnums=0)
-    layers = dict(srv.params_t["layers"])
-    layers["attn"] = dict(layers["attn"])
-    layers["attn"]["o"] = {"w": zero(layers["attn"]["o"]["w"])}
-    layers["mlp"] = dict(layers["mlp"])
-    layers["mlp"]["down"] = {"w": zero(layers["mlp"]["down"]["w"])}
+    layers = srv.params_t["layers"]
+    for path in family(cfg).late_writers(cfg):
+        layers = _replace(layers, path.split("."), zero)
     srv.params_t = dict(srv.params_t, layers=layers)
     served.params[0] = srv.params_t
+
+
+def _replace(tree: dict, keys: list, fn) -> dict:
+    """A copy of the nested dict ``tree`` with ``fn`` applied to the leaf at
+    ``keys``; every other leaf is the same array."""
+    head, *rest = keys
+    return dict(tree, **{head: _replace(tree[head], rest, fn) if rest
+                         else fn(tree[head])})
 
 
 FAULTS = {f.__name__: f for f in (token_altered, state_unchanged,

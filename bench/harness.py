@@ -2,9 +2,13 @@
 
 A cell (``BENCHMARK.json`` ``workloads`` entry) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<mix>.json``); per-layer metrics are read by
+(``bench/traffic/<mix>.json``). The configuration names its model family
+(``family``, ``dense`` where absent: ``bench/families/<family>.py``, which
+builds the program's ModelConfig and the seeded weights and counts the
+work) and its plain reference (``reference``:
+``bench/references/<reference>.py``); per-layer metrics are read by
 ``bench/metrics/<metric>.py``. Everything is found by name, so a new cell,
-mix or metric needs only new files and entries.
+mix, metric or model family needs only new files and entries.
 
 The window drives the program's served path as ``repro.launch.serve_paged``
 builds it (Planner -> Session -> PagedSpecServer, gamma pinned) behind its
@@ -28,6 +32,8 @@ from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
+
+from bench.families import family
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -131,22 +137,6 @@ class Served:
     params: list             # every params tree the server holds
 
 
-def model_config(cfg: dict, layers: Optional[int] = None, name=None):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=name or cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"] if layers is None else layers,
-        d_model=cfg["hidden_size"], num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        dtype=cfg["torch_dtype"], param_dtype=cfg["torch_dtype"],
-        source=cfg["source"])
-
-
 def geometry(mx: dict) -> dict:
     """Block geometry that holds the mix's worst request in a row and the
     whole batch's worst case in the pool (plus one spare row, as the
@@ -167,12 +157,13 @@ def build(cfg: dict, mx: dict, seed: int) -> Served:
     from repro.models.model import build_model
     from repro.serving import ServeRequest
 
-    from bench import weights
+    fam = family(cfg)
     k = cfg["self_draft"]["layers"]
-    pt = weights.target_params(cfg, seed)
-    pd = weights.drafter_params(cfg, pt)
-    mt = build_model(model_config(cfg))
-    md = build_model(model_config(cfg, layers=k, name=cfg["name"] + ".draft"))
+    pt = fam.target_params(cfg, seed)
+    pd = fam.drafter_params(cfg, pt)
+    mt = build_model(fam.model_config(cfg))
+    md = build_model(fam.model_config(cfg, layers=k,
+                                      name=cfg["name"] + ".draft"))
     geo = geometry(mx)
     argv = ["--arch", cfg["name"], "--gamma", str(cfg["self_draft"]["gamma"]),
             "--batch", str(mx["batch"]),
@@ -408,9 +399,8 @@ def logit_gaps(cfg: dict, seed: int, sample, prompts: dict, pad: int,
     import jax
     import jax.numpy as jnp
 
-    from bench import weights
     ref = reference(cfg)
-    params = weights.target_params(cfg, seed)
+    params = family(cfg).target_params(cfg, seed)
     fn = jax.jit(lambda p, t, s: ref.served_gaps(cfg, p, t, s, control))
     out = []
     for rec in sample:

@@ -1,9 +1,10 @@
 """Model FLOPs of the window over what the cell's chips could do in it at
 their bf16 peak. Model FLOPs: 2 x the target's matmul parameters per prompt
 token prefilled and per output token committed, plus attention over each
-token's context (``bench.counts``); rejected drafts and the drafter's own
-work do not count. Prompt tokens count for the requests whose first token
-came in the window; output tokens for those received in it."""
+token's context, capped at each layer's window (``bench.counts``); rejected
+drafts and the drafter's own work do not count. Prompt tokens count for the
+requests whose first token came in the window; output tokens for those
+received in it."""
 from bench import counts
 
 
@@ -11,9 +12,8 @@ def _tokens(cfg, start, n):
     """FLOPs of ``n`` tokens at positions start .. start + n - 1."""
     if n <= 0:
         return 0.0
-    ctx = n * (start + 1) + n * (n - 1) / 2      # sum of (position + 1)
     return 2.0 * counts.matmul_params(cfg) * n \
-        + counts.attention_flops(cfg, 1, 1) * ctx
+        + counts.causal_attention_flops(cfg, start, n)
 
 
 def read(run):

@@ -4,9 +4,10 @@ Written from the published description of the Llama-style block, in
 straightforward ``jax.numpy`` at ``highest`` matmul precision, with no
 kernel, cache or batching: one full causal pass over one sequence. It
 imports nothing of the program under test. The weights it reads are the
-benchmark's own, rebuilt from the seed (``bench.weights``), kept in the
-dtype they are served in and upcast one layer at a time inside the scan, so
-that the whole model never exists in float32.
+benchmark's own, rebuilt from the seed by the family module
+(``bench/families/dense.py``), kept in the dtype they are served in and
+upcast one layer at a time inside the scan, so that the whole model never
+exists in float32.
 
 ``control=True`` computes every linear layer (projections, MLP and head) in
 float8 e4m3: each weight column and each activation row scaled to the

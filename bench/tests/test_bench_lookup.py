@@ -1,5 +1,5 @@
-"""The harness finds every configuration, mix and metric by name, and
-refuses to run without a TPU."""
+"""The harness finds every configuration, family, mix and metric by name,
+and refuses to run without a TPU."""
 import json
 import os
 import shutil
@@ -19,6 +19,10 @@ def test_every_cell_finds_its_files():
         mx = harness.mix(cell["traffic"])
         assert cfg["name"] == cell["config"]
         assert harness.reference(cfg).logits is not None
+        fam = harness.family(cfg)
+        for fn in ("model_config", "target_params", "drafter_params",
+                   "late_writers", "matmul_params", "attention_windows"):
+            assert callable(getattr(fam, fn))
         assert mx["loop"] in ("open", "closed")
         assert cell["chips"] in (1, 4)
     files = {c["name"]: c["file"] for c in BENCH["configs"]}
