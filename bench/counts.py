@@ -2,10 +2,13 @@
 
 Kept with the benchmark so that no later change to the program can change
 how its work is counted. ``cfg`` is a configuration file's dict; what
-depends on the model family (the parameters in matrix products, each
-layer's attention window) comes from its family module
-(``bench.families.family``). A layer with a window reads at most that
-many keys.
+depends on the model family comes from its family module
+(``bench.families.family``): the parameters in matrix products, the FLOPs
+outside them and attention, and which layers hold K/V, each with its own
+record (``AttentionLayer``: window, K/V bytes a cached token holds, FLOPs a
+query spends per key, query and output bytes). Attention is summed over
+those records alone: a layer with no K/V reads no keys and costs nothing
+here, and a layer with a window reads at most that many keys.
 """
 from __future__ import annotations
 
@@ -16,6 +19,12 @@ def matmul_params(cfg: dict, layers: int = None) -> int:
     """Parameters that take part in a matrix product per token, over the
     first ``layers`` layers and the head (the family's count)."""
     return family(cfg).matmul_params(cfg, layers)
+
+
+def attention_layers(cfg: dict, layers: int = None) -> list:
+    """The family's ``AttentionLayer`` for each of the first ``layers``
+    layers that holds K/V."""
+    return family(cfg).attention_layers(cfg, layers)
 
 
 def _key_span(first: int, n: int, window) -> int:
@@ -32,51 +41,60 @@ def _key_span(first: int, n: int, window) -> int:
 
 def keys_read(cfg: dict, first: int, n: int = 1, layers: int = None) -> int:
     """Keys that ``n`` queries seeing ``first`` .. ``first + n - 1`` keys
-    read, summed over the first ``layers`` layers, each layer's capped at
-    its window."""
-    return sum(_key_span(first, n, w)
-               for w in family(cfg).attention_windows(cfg, layers))
+    read, summed over the attending layers among the first ``layers``, each
+    layer's capped at its window."""
+    return sum(_key_span(first, n, a.window)
+               for a in attention_layers(cfg, layers))
+
+
+def _key_flops(cfg: dict, first: int, n: int, layers: int) -> int:
+    """Score and value FLOPs of ``n`` queries seeing ``first`` .. ``first +
+    n - 1`` keys: each attending layer's keys at its FLOPs per key."""
+    return sum(_key_span(first, n, a.window) * a.flops_per_key
+               for a in attention_layers(cfg, layers))
 
 
 def attention_flops(cfg: dict, queries: int, context: int,
                     layers: int = None) -> float:
     """Score and value products of ``queries`` query tokens that each see
-    ``context`` keys (or a layer's window, where it is shorter), over
-    ``layers`` layers: 4 * q * keys * heads * head_dim per layer."""
-    return 4.0 * queries * keys_read(cfg, context, 1, layers) \
-        * cfg["num_attention_heads"] * cfg["head_dim"]
+    ``context`` keys (or a layer's window, where it is shorter), over the
+    attending layers among the first ``layers``."""
+    return float(queries * _key_flops(cfg, context, 1, layers))
 
 
 def causal_attention_flops(cfg: dict, start: int, n: int,
                            layers: int = None) -> float:
     """Score and value products of ``n`` tokens at positions ``start`` ..
     ``start + n - 1``, each seeing the keys up to its own position."""
-    return 4.0 * keys_read(cfg, start + 1, n, layers) \
-        * cfg["num_attention_heads"] * cfg["head_dim"]
+    return float(_key_flops(cfg, start + 1, n, layers))
 
 
-def token_flops(cfg: dict, context: int) -> float:
-    """Model FLOPs of one token at position ``context`` (it attends to
-    ``context + 1`` keys)."""
-    return 2.0 * matmul_params(cfg) + attention_flops(cfg, 1, context + 1)
+def token_flops(cfg: dict, start: int, n: int = 1) -> float:
+    """Model FLOPs of ``n`` tokens at positions ``start`` .. ``start + n -
+    1`` (the token at ``start`` attends to ``start + 1`` keys): the matmuls,
+    attention, and the family's FLOPs outside both."""
+    if n <= 0:
+        return 0.0
+    return 2.0 * matmul_params(cfg) * n \
+        + causal_attention_flops(cfg, start, n) \
+        + family(cfg).other_flops(cfg, start, n)
 
 
-def kv_bytes_per_token(cfg: dict, layers: int = None,
-                       itemsize: int = 2) -> int:
-    """Key and value bytes one cached token holds over ``layers`` layers."""
-    L = cfg["num_hidden_layers"] if layers is None else layers
-    return 2 * L * cfg["num_key_value_heads"] * cfg["head_dim"] * itemsize
+def kv_bytes_per_token(cfg: dict, layers: int = None) -> int:
+    """Key and value bytes one cached token holds over the attending layers
+    among the first ``layers``."""
+    return sum(a.kv_bytes for a in attention_layers(cfg, layers))
 
 
 def paged_attention_least(cfg: dict, queries: int, context: int,
-                          layers: int, itemsize: int = 2) -> tuple:
-    """(flops, bytes) that one row's paged-attention calls over ``layers``
-    layers cannot do without: ``queries`` queries against ``context`` cached
-    tokens (a windowed layer's last ``window``), each key and value read
-    once, the queries read and the outputs written once."""
-    nq, hd = cfg["num_attention_heads"], cfg["head_dim"]
+                          layers: int) -> tuple:
+    """(flops, bytes) that one row's paged-attention calls over the
+    attending layers among the first ``layers`` cannot do without:
+    ``queries`` queries against ``context`` cached tokens (a windowed
+    layer's last ``window``), each key and value read once, the queries
+    read and the outputs written once."""
     flops = attention_flops(cfg, queries, context, layers)
-    kv = kv_bytes_per_token(cfg, 1, itemsize) \
-        * keys_read(cfg, context, 1, layers)
-    qo = 2 * queries * nq * hd * itemsize * layers
-    return flops, kv + qo
+    nbytes = sum(_key_span(context, 1, a.window) * a.kv_bytes
+                 + queries * a.query_bytes
+                 for a in attention_layers(cfg, layers))
+    return flops, nbytes

@@ -3,6 +3,16 @@ benchmark's whole knowledge of one family's program-facing shape."""
 from __future__ import annotations
 
 import importlib
+from typing import NamedTuple, Optional
+
+
+class AttentionLayer(NamedTuple):
+    """What one layer that holds K/V holds and costs, as its family's
+    ``attention_layers`` gives it (``bench.counts`` sums over them)."""
+    window: Optional[int]    # keys a query reads at most; None: all
+    kv_bytes: int            # bytes one cached token holds in this layer
+    flops_per_key: int       # score and value FLOPs of one query per key
+    query_bytes: int         # bytes one query reads and its output writes
 
 
 def family(cfg: dict):

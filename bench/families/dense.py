@@ -9,13 +9,23 @@ Every family module gives:
 - ``model_config(cfg, layers=None, name=None)``: the program's ModelConfig;
 - ``target_params(cfg, seed)``, ``drafter_params(cfg, target)``: the seeded
   weights in the layout the program reads, and the self-drafter;
-- ``late_writers(cfg)``: the per-layer leaves (dotted paths under
-  ``layers``) that write to the residual stream; the weight builder damps
-  them past the drafter's depth and ``faults.skip_late_layers`` zeroes them;
+- ``late_writers(cfg)``: the residual-writing leaves (dotted paths under
+  ``layers``, which may be one stack or a dict of per-kind stacks); the
+  weight builder damps their entries past the drafter's depth;
+- ``stack_layers(cfg, path)``: the model layer of each entry along the
+  leading axis of the ``late_writers`` leaf at ``path``
+  (``faults.skip_late_layers`` zeroes those at or past the drafter's depth);
 - ``matmul_params(cfg, layers=None)``: parameters in a matrix product per
   token (``bench.counts``);
-- ``attention_windows(cfg, layers=None)``: per layer, the window in tokens,
-  or ``None`` for full attention (``bench.counts``).
+- ``attention_layers(cfg, layers=None)``: one ``AttentionLayer`` for each
+  of the first ``layers`` layers that holds K/V, in order: its window (or
+  ``None``), the K/V bytes one cached token holds there, the FLOPs one
+  query spends per key, the bytes one query reads and writes; a layer with
+  no K/V has no record (``bench.counts``, the roofline reader: one
+  paged-attention call a round per record);
+- ``other_flops(cfg, start, n)``: FLOPs of ``n`` tokens at positions
+  ``start`` .. ``start + n - 1`` outside parameter matmuls and attention,
+  such as a state-space scan (``bench.counts.token_flops``).
 
 The weights follow ``bench.weights``: built on the device in one jitted call
 from the seed, layer by layer inside a ``lax.map``, in the served dtype.
@@ -25,6 +35,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from bench.families import AttentionLayer
 from bench.weights import _normal, dtype_of, seed_key
 
 
@@ -126,7 +137,23 @@ def matmul_params(cfg: dict, layers: int = None) -> int:
     return L * per_layer + d * cfg["vocab_size"]
 
 
-def attention_windows(cfg: dict, layers: int = None) -> list:
-    """Every layer attends to its whole context."""
+def stack_layers(cfg: dict, path: str) -> list:
+    """Every leaf is one stack, entry ``i`` model layer ``i``."""
+    return list(range(cfg["num_hidden_layers"]))
+
+
+def attention_layers(cfg: dict, layers: int = None) -> list:
+    """Every layer attends to its whole context with the configuration's
+    heads: K and V of ``num_key_value_heads`` x ``head_dim``, 2 FLOPs a
+    multiply-add for the score and the value of each query head."""
     L = cfg["num_hidden_layers"] if layers is None else layers
-    return [None] * L
+    size = dtype_of(cfg).itemsize
+    nq, nkv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                   cfg["head_dim"])
+    return [AttentionLayer(None, 2 * nkv * hd * size, 4 * nq * hd,
+                           2 * nq * hd * size)] * L
+
+
+def other_flops(cfg: dict, start: int, n: int) -> int:
+    """Nothing outside the matmuls and attention is counted."""
+    return 0

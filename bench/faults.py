@@ -46,15 +46,26 @@ def state_unchanged(served, cfg: dict) -> None:
 
 def skip_late_layers(served, cfg: dict) -> None:
     """The target's layers past the drafter's depth add nothing to the
-    residual stream (the leaves through which they write to it, the
-    family's ``late_writers``, zeroed in place): every program of the served
+    residual stream: in each of the family's ``late_writers`` leaves, the
+    entries that its ``stack_layers`` puts at model layers at or past that
+    depth are zeroed in place, and no other. Every program of the served
     path then serves what the drafter's own layers give."""
     k = cfg["self_draft"]["layers"]
+    fam = family(cfg)
     srv = served.srv
-    zero = jax.jit(lambda w: w.at[k:].set(0), donate_argnums=0)
+    zero = jax.jit(lambda w, idx: w.at[idx].set(0), donate_argnums=0)
     layers = srv.params_t["layers"]
-    for path in family(cfg).late_writers(cfg):
-        layers = _replace(layers, path.split("."), zero)
+    for path in fam.late_writers(cfg):
+        owners = fam.stack_layers(cfg, path)
+        late = jnp.asarray([i for i, layer in enumerate(owners) if layer >= k],
+                           jnp.int32)
+
+        def zero_late(w):
+            if w.shape[0] != len(owners):
+                raise ValueError(f"{path}: {w.shape[0]} entries, but "
+                                 f"stack_layers names {len(owners)}")
+            return zero(w, late)
+        layers = _replace(layers, path.split("."), zero_late)
     srv.params_t = dict(srv.params_t, layers=layers)
     served.params[0] = srv.params_t
 

@@ -4,8 +4,11 @@ A cell (``BENCHMARK.json`` ``workloads`` entry) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<mix>.json``). The configuration names its model family
 (``family``, ``dense`` where absent: ``bench/families/<family>.py``, which
-builds the program's ModelConfig and the seeded weights and counts the
-work) and its plain reference (``reference``:
+builds the program's ModelConfig and the seeded weights, and says which
+layers hold K/V and what each holds and costs per token, which stack entries
+belong to which model layer, and the FLOPs outside matmuls and attention:
+the counts, the roofline reader and the planted faults read these from it)
+and its plain reference (``reference``:
 ``bench/references/<reference>.py``); per-layer metrics are read by
 ``bench/metrics/<metric>.py``. Everything is found by name, so a new cell,
 mix, metric or model family needs only new files and entries.

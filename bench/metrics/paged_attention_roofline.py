@@ -6,12 +6,14 @@ Device time: the ``paged_attention`` events of the traced window whose
 result has one row per batch row (the decode rounds' calls; a prefill
 chunk's calls have one row), summed and divided by the rounds traced, which
 is the number of verify calls (``gamma + 1`` queries per row) over the
-target's layers.
+target's attending layers: one call a round for each layer that holds K/V
+(``bench.counts.attention_layers``).
 
 Least time of a round: for each live row of committed length ``n``, the
-verify's ``gamma + 1`` queries over the target's layers and the ``gamma``
-draft steps' single queries over the drafter's layers, each reading every
-cached key and value once (``bench.counts.paged_attention_least``); the
+verify's ``gamma + 1`` queries over the target's attending layers and the
+``gamma`` draft steps' single queries over the attending layers among the
+drafter's (none: nothing), each reading every cached key and value once
+(``bench.counts.paged_attention_least``, from each layer's record); the
 larger of its operations at the bf16 peak and its bytes at the HBM peak.
 Averaged over the speculative rounds that ended in the traced window. Each
 row's committed length is rebuilt from the round events: its prompt, plus
@@ -40,6 +42,7 @@ def read(run):
         return None
     gamma = cfg["self_draft"]["gamma"]
     k, L = cfg["self_draft"]["layers"], cfg["num_hidden_layers"]
+    attending = len(counts.attention_layers(cfg))
     group = cfg["num_attention_heads"] // cfg["num_key_value_heads"]
     verify_rows = -(-(gamma + 1) * group // 8) * 8    # the kernel pads to 8
     lo, hi = w.trace_span
@@ -68,6 +71,6 @@ def read(run):
             busy += (e - s) * 1e-9
             verify_calls += shape[2] == verify_rows
         if least and verify_calls:
-            per_round = busy / (verify_calls / L)
+            per_round = busy / (verify_calls / attending)
             shares.append(sum(least) / len(least) / per_round)
     return 100.0 * sum(shares) / len(shares) if shares else None

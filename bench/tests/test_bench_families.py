@@ -1,7 +1,9 @@
 """Model families, found by name: the dense family builds what the harness
 built before families had modules of their own (the same ModelConfig,
-bit-identical weights, the same counts), a windowed family's counts cap each
-layer at its window, an unknown family fails at lookup, and a family
+bit-identical weights, the same counts and roofline share), a windowed
+family's counts cap each layer at its window, a hybrid-shaped family's
+counts, roofline share and planted fault take only its attending layers and
+its per-kind stacks, an unknown family fails at lookup, and a family
 registered under a new name runs a whole cell on the CPU, its planted fault
 included."""
 import dataclasses
@@ -16,14 +18,16 @@ import numpy as np
 import pytest
 
 from bench import counts, faults, harness
-from bench.families import dense
+from bench.families import AttentionLayer, dense
+from bench.trace_reduce import DeviceTrace
 from bench.weights import _normal, dtype_of, seed_key
 
 CONFIGS = ["granite3-2b.sd4", "dscoder33b-l8.sd1"]
 DATA = harness.BENCH / "tests" / "data"
 TINY = harness.load_json(DATA / "tiny.sd1.json")
 ENTRY_POINTS = ("model_config", "target_params", "drafter_params",
-                "late_writers", "matmul_params", "attention_windows")
+                "late_writers", "stack_layers", "matmul_params",
+                "attention_layers", "other_flops")
 
 
 # ------------------------------------------------ the dense family, pinned
@@ -161,12 +165,71 @@ def test_dense_counts_are_the_pinned_ones(name):
     assert dense.matmul_params(cfg) == counts.matmul_params(cfg) \
         == want["matmul"]
     assert counts.matmul_params(cfg, k) == want["draft_matmul"]
-    assert dense.attention_windows(cfg) == [None] * L
+    nq, nkv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                   cfg["head_dim"])
+    assert dense.attention_layers(cfg) == [AttentionLayer(
+        None, 2 * nkv * hd * 2, 4 * nq * hd, 2 * nq * hd * 2)] * L
+    assert dense.stack_layers(cfg, "mlp.down.w") == list(range(L))
+    assert dense.other_flops(cfg, 3000, 5) == 0
     assert counts.token_flops(cfg, 0) == want["token0"]
     assert counts.token_flops(cfg, 3000) == want["token3000"]
     assert counts.paged_attention_least(cfg, 5, 3000, L) == want["verify"]
     assert counts.paged_attention_least(cfg, 1, 3001, k) == want["draft"]
     assert harness.reader("step_mfu")(_window_run(cfg)) == want["step_mfu"]
+
+
+def _round_run(cfg, batch, verify_calls, draft_calls):
+    """A traced window of eight rounds of two to four rows (some with a row
+    that has no record, one autoregressive, one before the traced span), and
+    per round ``draft_calls`` and ``verify_calls`` paged-attention calls at
+    the configuration's result shapes, a prefill chunk's call, and an op
+    that only reads the kernel's result."""
+    gamma = cfg["self_draft"]["gamma"]
+    kv, hd = cfg["num_key_value_heads"], cfg["head_dim"]
+    group = cfg["num_attention_heads"] // kv
+    recs = [NS(idx=i, prompt_len=p) for i, p in enumerate([300, 1200, 40,
+                                                            2900])]
+    events, ops, t = [], [], 0
+
+    def call(i, rows, ns, b=batch):
+        nonlocal t
+        rows = -(-rows // 8) * 8
+        ops.append((f"%paged_attention.{i} = bf16[{b},{kv},{rows},{hd}]"
+                    "{3,2,1,0} custom-call(bf16[4]{0} %p.1)", t, t + ns))
+        t += ns + 500
+    for r in range(8):
+        g = 0 if r == 3 else gamma
+        n = 2 + r % 3
+        events.append(NS(rids=[0, 1, 7, 2][:n], gamma=g, t_wall=10.0 + 0.5 * r,
+                         accepted=[r % (g + 1), 2 * r % (g + 1), 1, 0][:n]))
+        for j in range(draft_calls if g else 0):
+            call(24, group, 9000 + 700 * (j % 5))
+        for j in range(verify_calls):
+            call(16, (g + 1) * group, 41000 + 900 * (j % 7) + 300 * r)
+        call(8, 256, 120000, b=1)
+        ops.append(("%fusion.3 = bf16[4]{0} fusion(bf16[4]{0} "
+                    "%paged_attention.16)", t, t + 2000))
+        t += 3000
+    return NS(cfg=cfg, srv_batch=batch,
+              peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+              window=NS(records=recs, events=events, trace_span=(10.2, 13.6),
+                        trace={"devices": [DeviceTrace("/device:TPU:0",
+                                                       ops)]}))
+
+
+# what bench/metrics/paged_attention_roofline.py gave on _round_run before
+# a family named its attending layers
+PINNED_ROOFLINE = {"granite3-2b.sd4": (16, 9.963872230184782),
+                   "dscoder33b-l8.sd1": (32, 21.42860483496888)}
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_dense_roofline_is_the_pinned_one(name):
+    cfg = harness.config(name)
+    batch, want = PINNED_ROOFLINE[name]
+    L, sd = cfg["num_hidden_layers"], cfg["self_draft"]
+    run = _round_run(cfg, batch, L, sd["gamma"] * sd["layers"])
+    assert harness.reader("paged_attention_roofline")(run) == want
 
 
 # --------------------------------------------------- families found by name
@@ -185,15 +248,15 @@ def _wrapping_family(name: str, calls: list, **override):
 
 
 def _windowed(cfg, layers=None):
-    """A 1024-token window on three of every four layers."""
-    L = cfg["num_hidden_layers"] if layers is None else layers
-    return [None if i % 4 == 3 else 1024 for i in range(L)]
+    """Dense's layers, with a 1024-token window on three of every four."""
+    return [a._replace(window=None if i % 4 == 3 else 1024)
+            for i, a in enumerate(dense.attention_layers(cfg, layers))]
 
 
 def test_a_windowed_family_caps_each_layer_at_its_window(monkeypatch):
     monkeypatch.setitem(sys.modules, "bench.families.windowed",
                         _wrapping_family("windowed", [],
-                                         attention_windows=_windowed))
+                                         attention_layers=_windowed))
     g = harness.config("granite3-2b.sd4")
     cfg = dict(g, family="windowed")
     per_layer_kv = counts.kv_bytes_per_token(g, layers=1)
@@ -218,14 +281,200 @@ def test_causal_key_counts_cap_at_the_window(window, first, n, monkeypatch):
     monkeypatch.setitem(sys.modules, "bench.families.one_window",
                         _wrapping_family(
                             "one_window", [],
-                            attention_windows=lambda c, layers=None:
-                            [window] * (2 if layers is None else layers)))
+                            attention_layers=lambda c, layers=None:
+                            [a._replace(window=window)
+                             for a in dense.attention_layers(c, layers)]))
     cfg = dict(TINY, family="one_window", num_hidden_layers=2)
     keys = sum(c if window is None else min(c, window)
                for c in range(first, first + n))
     assert counts.keys_read(cfg, first, n) == 2 * keys
     assert counts.causal_attention_flops(cfg, first - 1, n) == \
         4.0 * 2 * keys * TINY["num_attention_heads"] * TINY["head_dim"]
+
+
+# ------------------------------- a hybrid-shaped family: 1 layer in 4 attends
+def _attends(i):
+    return i % 4 == 3
+
+
+def _hybrid_layers(cfg, layers=None):
+    """Dense's record, on layers 3, 7, 11, ... only."""
+    L = cfg["num_hidden_layers"] if layers is None else layers
+    one = dense.attention_layers(cfg, 1)[0]
+    return [one for i in range(L) if _attends(i)]
+
+
+def _hybrid_stacks(cfg, path):
+    """Per-kind stacks: the mixers' stack holds the layers that do not
+    attend, the attention stack those that do, the MLPs' stack all."""
+    L = range(cfg["num_hidden_layers"])
+    kind = path.split(".")[0]
+    return {"mixer": [i for i in L if not _attends(i)],
+            "attn": [i for i in L if _attends(i)], "mlp": list(L)}[kind]
+
+
+OTHER = 1000      # FLOPs a token the hybrid-shaped family spends elsewhere
+
+
+@pytest.fixture
+def hybrid(monkeypatch):
+    """Granite's widths, 40 layers of which 10 attend, its drafter's 4
+    layers holding one of them."""
+    monkeypatch.setitem(sys.modules, "bench.families.hybrid_shaped",
+                        _wrapping_family(
+                            "hybrid_shaped", [],
+                            attention_layers=_hybrid_layers,
+                            stack_layers=_hybrid_stacks,
+                            late_writers=lambda c: ["mixer.out.w",
+                                                    "attn.o.w", "mlp.down.w"],
+                            other_flops=lambda c, start, n: OTHER * n))
+    return dict(harness.config("granite3-2b.sd4"), family="hybrid_shaped")
+
+
+def test_a_hybrid_family_counts_its_attending_layers_alone(hybrid):
+    kv, fpk, qb = 2 * 8 * 64 * 2, 4 * 32 * 64, 2 * 32 * 64 * 2
+    assert counts.kv_bytes_per_token(hybrid) == 10 * kv
+    assert counts.kv_bytes_per_token(hybrid, layers=4) == kv
+    assert counts.paged_attention_least(hybrid, 5, 3000, 40) == \
+        (5 * 10 * 3000 * fpk, 10 * (3000 * kv + 5 * qb))
+    assert counts.paged_attention_least(hybrid, 1, 3001, 4) == \
+        (3001 * fpk, 3001 * kv + qb)
+    # the first three layers hold no K/V: no keys, FLOPs or bytes
+    assert counts.keys_read(hybrid, 100, 7, layers=3) == 0
+    assert counts.attention_flops(hybrid, 5, 100, layers=3) == 0
+    assert counts.causal_attention_flops(hybrid, 0, 50, layers=3) == 0
+    assert counts.kv_bytes_per_token(hybrid, layers=3) == 0
+    assert counts.paged_attention_least(hybrid, 1, 3001, 3) == (0, 0)
+    # one token at 0 sees one key in each attending layer
+    assert counts.token_flops(hybrid, 0) == \
+        2 * counts.matmul_params(hybrid) + 10 * fpk + OTHER
+
+
+def test_step_mfu_counts_the_family_flops_outside_matmuls(hybrid,
+                                                          monkeypatch):
+    with_other = harness.reader("step_mfu")(_window_run(hybrid))
+    monkeypatch.setattr(sys.modules["bench.families.hybrid_shaped"],
+                        "other_flops", lambda c, start, n: 0)
+    without = harness.reader("step_mfu")(_window_run(hybrid))
+    # prefill and committed tokens of the three requests that started
+    tokens = (299 + 40) + (2899 + 64) + (3999 + 1)
+    assert with_other - without == \
+        pytest.approx(100 * tokens * OTHER / (51.0 * 197e12), rel=1e-9)
+
+
+def test_a_hybrid_roofline_takes_one_call_a_round_per_attending_layer(
+        hybrid):
+    """Two speculative rounds of two rows, 1000 and 2000 tokens long, each
+    round 10 verify calls (one per attending layer) of 50 us and 4 draft
+    calls (the drafter's one attending layer, gamma 4) of 10 us: by hand,
+    each round's least time is its bytes at the HBM peak."""
+    kv, qb, fpk = 2 * 8 * 64 * 2, 2 * 32 * 64 * 2, 4 * 32 * 64
+    ops, t = [], 0
+    for _ in range(2):
+        for rows, ns, calls in ((8, 10_000, 4), (24, 50_000, 10)):
+            for _ in range(calls):
+                ops.append((f"%paged_attention.1 = bf16[2,8,{rows},64]"
+                            "{3,2,1,0} custom-call()", t, t + ns))
+                t += ns + 1000
+    run = NS(cfg=hybrid, srv_batch=2,
+             peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+             window=NS(records=[NS(idx=0, prompt_len=1000),
+                                NS(idx=1, prompt_len=2000)],
+                       events=[NS(rids=[0, 1], accepted=[2, 4], gamma=4,
+                                  t_wall=11.0),
+                               NS(rids=[0, 1], accepted=[0, 1], gamma=4,
+                                  t_wall=12.0)],
+                       trace_span=(10.0, 13.0),
+                       trace={"devices": [DeviceTrace("/device:TPU:0",
+                                                      ops)]}))
+    least = []
+    for lengths in ([1000, 2000], [1003, 2005]):
+        nbytes = flops = 0
+        for n in lengths:
+            nbytes += 10 * (n * kv + 5 * qb)        # verify, 10 layers
+            flops += 10 * 5 * n * fpk
+            for j in range(4):                      # drafts, 1 layer
+                nbytes += (n + j) * kv + qb
+                flops += (n + j) * fpk
+        assert nbytes / 819e9 > flops / 197e12
+        least.append(nbytes / 819e9)
+    round_s = (4 * 10_000 + 10 * 50_000) * 1e-9
+    assert harness.reader("paged_attention_roofline")(run) == \
+        pytest.approx(100 * sum(least) / 2 / round_s, rel=1e-12)
+
+
+def test_a_latent_record_is_counted_from_the_family(monkeypatch):
+    """A latent-attention-shaped layer caches one 576-wide latent a token
+    (512 + 64 for position), not K and V of ``num_key_value_heads`` x
+    ``head_dim``: its bytes and FLOPs per key are the family's."""
+    heads = 32
+    latent = AttentionLayer(window=None, kv_bytes=576 * 2,
+                            flops_per_key=2 * heads * 576 + 2 * heads * 512,
+                            query_bytes=heads * (576 + 512) * 2)
+    monkeypatch.setitem(sys.modules, "bench.families.latent",
+                        _wrapping_family(
+                            "latent", [],
+                            attention_layers=lambda c, layers=None:
+                            [latent] * (40 if layers is None else layers)))
+    cfg = dict(harness.config("granite3-2b.sd4"), family="latent")
+    assert counts.kv_bytes_per_token(cfg) == 40 * 1152
+    assert counts.paged_attention_least(cfg, 5, 3000, 40) == \
+        (5 * 40 * 3000 * (64 * 576 + 64 * 512),
+         40 * (3000 * 1152 + 5 * 32 * 1088 * 2))
+    assert counts.causal_attention_flops(cfg, 9, 1, layers=1) == \
+        10 * (64 * 576 + 64 * 512)
+
+
+def _stacks(owners: dict) -> dict:
+    """Nested dicts of one bf16 leaf per dotted path, its entry i filled
+    with i + 1."""
+    out = {}
+    for path, layers in owners.items():
+        *head, leaf = path.split(".")
+        node = out
+        for key in head:
+            node = node.setdefault(key, {})
+        n = len(layers)
+        node[leaf] = jnp.arange(1, n + 1, dtype=jnp.bfloat16)[:, None, None] \
+            * jnp.ones((n, 3, 2), jnp.bfloat16)
+    return out
+
+
+def _leaf(tree: dict, path: str):
+    for key in path.split("."):
+        tree = tree[key]
+    return np.asarray(tree)
+
+
+@pytest.mark.parametrize("shape", ["dense", "hybrid_shaped"])
+def test_skip_late_layers_zeroes_the_entries_of_late_layers(shape, hybrid):
+    """On dense's one stack, entries ``[k:]``, as before families named
+    their layers; on per-kind stacks, the entries of layers >= k alone."""
+    cfg = harness.config("granite3-2b.sd4") if shape == "dense" else hybrid
+    fam = harness.family(cfg)
+    k = cfg["self_draft"]["layers"]
+    owners = {p: fam.stack_layers(cfg, p) for p in fam.late_writers(cfg)}
+    before = _stacks(owners)
+    served = NS(srv=NS(params_t={"layers": _stacks(owners), "embed": "kept"}),
+                params=[None])
+    faults.skip_late_layers(served, cfg)
+    assert served.params[0] is served.srv.params_t
+    assert served.srv.params_t["embed"] == "kept"
+    zeroed = 0
+    for path, layers_of in owners.items():
+        got = _leaf(served.srv.params_t["layers"], path)
+        want = _leaf(before, path)
+        for i, layer in enumerate(layers_of):
+            if layer >= k:
+                zeroed += 1
+                assert not got[i].any(), (path, i, layer)
+            else:
+                np.testing.assert_array_equal(got[i].view(np.uint16),
+                                              want[i].view(np.uint16))
+    if shape == "dense":
+        assert zeroed == 2 * (40 - k)
+    else:           # mixers of layers 4..38 less 7, 11, ..; attention 7..39
+        assert zeroed == 27 + 9 + 36
 
 
 @pytest.mark.parametrize("name", ["no_such_family", "not.a.module"])
@@ -271,3 +520,4 @@ def test_a_family_registered_by_name_runs_a_whole_cell(fault, monkeypatch):
     assert calls.count("target_params") == 2       # served, then re-read
     assert calls.count("drafter_params") == 1
     assert ("late_writers" in calls) is (fault is not None)
+    assert ("stack_layers" in calls) is (fault is not None)

@@ -21,7 +21,8 @@ def test_every_cell_finds_its_files():
         assert harness.reference(cfg).logits is not None
         fam = harness.family(cfg)
         for fn in ("model_config", "target_params", "drafter_params",
-                   "late_writers", "matmul_params", "attention_windows"):
+                   "late_writers", "stack_layers", "matmul_params",
+                   "attention_layers", "other_flops"):
             assert callable(getattr(fam, fn))
         assert mx["loop"] in ("open", "closed")
         assert cell["chips"] in (1, 4)
